@@ -46,5 +46,8 @@ def run(quick: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache()
     for r in run():
         print(",".join(str(c) for c in r))

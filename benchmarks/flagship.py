@@ -200,10 +200,12 @@ def _run_report(args) -> dict:
 def run(quick: bool = False):
     """benchmarks.run entry: [(name, us_per_call, derived), …].
 
-    Spawns worker subprocesses; in an environment where that is not
-    possible (no free ports, sandboxed exec) the failure surfaces as
+    Spawns worker subprocesses; on the CPU, where that is not possible
+    (no free ports, sandboxed exec), the failure surfaces as
     :class:`benchmarks.run.SuiteSkipped` so the harness reports *why*
-    the suite produced no rows instead of failing the whole run.
+    the suite produced no rows instead of failing the whole run. On an
+    accelerator the spawn is not a simulation any more, and a worker
+    failure fails the suite.
     """
     from benchmarks.run import SuiteSkipped
 
@@ -216,6 +218,12 @@ def run(quick: bool = False):
     try:
         report = _run_report(parse_args(argv))
     except (SystemExit, OSError, subprocess.SubprocessError) as e:
+        import jax
+
+        if jax.default_backend() != "cpu":
+            raise RuntimeError(
+                f"flagship workers failed on {jax.default_backend()}: {e}"
+            ) from e
         raise SuiteSkipped(f"multi-process spawn unavailable: {e}") from e
     rows = [
         (f"flagship.{name}", d["wall_s"] * 1e6, "")

@@ -2,10 +2,11 @@
 
 Enumerates :mod:`repro.kernels.registry` — every registered kernel is timed
 on its declared ``bench_shapes`` working point, Pallas path vs jnp oracle
-at equal shapes. Kernels with a ``cost_model`` get achieved-vs-roofline
-columns (GFLOP/s, GB/s, fraction of the v5e roofline bound — on CPU these
-fractions are tiny by construction; the TPU peaks are the fixed reference
-frame, so the numbers stay comparable across machines).
+at equal shapes. On a device with a row in
+:data:`repro.roofline.analysis.PEAKS`, kernels with a ``cost_model`` get
+achieved-vs-roofline columns (GFLOP/s, GB/s, fraction of the roofline
+bound); elsewhere (the CPU) those columns are left out, since a CPU time
+says nothing about the chip.
 
 ``step_compare`` times the production question behind the fusion: ONE
 jitted SGD step through the fused ``nomad_step`` dispatch vs the same
@@ -33,7 +34,7 @@ import jax.numpy as jnp
 
 from repro.core import losses
 from repro.kernels import autotune, registry
-from repro.roofline.analysis import kernel_roofline
+from repro.roofline.analysis import PEAKS, kernel_roofline
 
 
 def _time(fn, *args, reps=3):
@@ -49,12 +50,19 @@ def _shape_label(sig) -> str:
     return "-".join("x".join(str(d) for d in shape) for shape, _dt in sig)
 
 
+def _device_peaks():
+    """The PEAKS row of this device, or None where it has none (the CPU)."""
+    return PEAKS.get(jax.devices()[0].device_kind)
+
+
 def _roofline_cols(spec, us):
-    """" gflops=… gbs=… bound=… roofline_frac=…" or "" without a cost model."""
-    if spec.cost_model is None or us is None:
+    """" gflops=… gbs=… bound=… roofline_frac=…", or "" without a cost
+    model or without published peaks for this device."""
+    hw = _device_peaks()
+    if spec.cost_model is None or us is None or hw is None:
         return "", None
     cost = spec.cost_model(spec.bench_shapes)
-    rl = kernel_roofline(cost["flops"], cost["bytes"], us)
+    rl = kernel_roofline(cost["flops"], cost["bytes"], us, hw)
     txt = (
         f" gflops={rl['gflops']:.2f} gbs={rl['gbs']:.2f}"
         f" bound={rl['bound']} roofline_frac={rl['roofline_frac']:.2e}"
@@ -192,12 +200,13 @@ def _report():
             print(f"{name}: jnp-only (no tile grid)")
             continue
         entry = autotune.sweep(spec, spec.bench_shapes, report=True)
-        cost = spec.cost_model(spec.bench_shapes) if spec.cost_model else None
+        hw = _device_peaks()
+        cost = spec.cost_model(spec.bench_shapes) if spec.cost_model and hw else None
         print(f"{name} @ {_shape_label(spec.bench_shapes)} (winner {entry['tiles']}):")
         for cand in entry.get("candidates", []):
             line = f"  tiles={cand['tiles']} us={cand['us']:.1f}"
             if cost:
-                rl = kernel_roofline(cost["flops"], cost["bytes"], cand["us"])
+                rl = kernel_roofline(cost["flops"], cost["bytes"], cand["us"], hw)
                 line += (
                     f" gflops={rl['gflops']:.2f} gbs={rl['gbs']:.2f}"
                     f" bound={rl['bound']} roofline_us={rl['roofline_us']:.3f}"
@@ -255,7 +264,7 @@ def main() -> int:
             if spec.pallas is None:
                 continue
             entry = autotune.sweep(spec, spec.bench_shapes)
-            if cache and entry.get("us") is not None:
+            if cache:
                 autotune.record(spec, spec.bench_shapes, entry)
             print(f"{name}: winner={entry['tiles']} us={entry.get('us')}")
         if cache:
@@ -275,4 +284,7 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

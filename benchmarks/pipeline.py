@@ -141,4 +141,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

@@ -112,4 +112,7 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

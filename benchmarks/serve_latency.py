@@ -152,6 +152,9 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache()
     import sys
 
     sys.exit(main())

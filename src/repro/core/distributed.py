@@ -39,7 +39,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import NomadConfig
@@ -170,7 +170,7 @@ def make_sharded_epoch_fn(
         mesh=mesh,
         in_specs=specs_in,
         out_specs=(P(*row_spec, None), P()),
-        check_rep=False,
+        check_vma=False,
     )
     def epoch(theta_l, idx_l, counts_global, lr0, lr1, key):
         shard_idx, _ = shard_index_and_count(mesh, all_axes)

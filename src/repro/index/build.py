@@ -47,7 +47,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import NomadConfig
@@ -169,7 +169,7 @@ def _capacity_rounds_sharded(
         mesh=mesh,
         in_specs=(P(BUILD_AXIS, None), P(None, None)),
         out_specs=(P(None), P(None)),
-        check_rep=False,
+        check_vma=False,
     )
     def run(x_local, cents):
         cand_idx, cand_d2 = _candidate_pass(x_local, cents, n_cand, impl, blk)

@@ -418,7 +418,7 @@ def kmeans_fit_sharded(
     per-device partials in the same mesh order either way.
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from repro.kernels import registry
 
@@ -440,7 +440,7 @@ def kmeans_fit_sharded(
         mesh=mesh,
         in_specs=(P(axis, None), P(axis), P(None, None)),
         out_specs=P(None, None),
-        check_rep=False,
+        check_vma=False,
     )
     def em_iters(x_local, w_local, cents):
         cents, _a, _c, _done = _em_scan(

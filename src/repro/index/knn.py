@@ -20,6 +20,10 @@ from repro.core.rank_model import edge_weights
 
 BIG = jnp.float32(1e30)
 
+# bytes of (C, C) f32 distance tiles one batch of clusters may hold at once;
+# each cluster's kNN keeps several such tiles (distances, masks, ranks)
+_KNN_TILE_BYTES = 256 << 20
+
 
 def _pairwise_dist2_jnp(xb: jax.Array) -> jax.Array:
     x2 = jnp.sum(jnp.square(xb), -1)
@@ -75,10 +79,20 @@ def batched_cluster_knn(
     from repro.kernels import registry
 
     impl = deprecate_use_pallas(impl, use_pallas, "batched_cluster_knn")
-    resolved = registry.resolve("pairwise", impl)
-    return jax.vmap(lambda xb, vb: _cluster_knn_jit(xb, vb, k, resolved))(
-        x_blocks, valid
-    )
+    return _knn_over_clusters(x_blocks, valid, k, registry.resolve("pairwise", impl))
+
+
+def _knn_over_clusters(x_blocks, valid, k: int, impl: str):
+    """vmap of :func:`_cluster_knn_jit` over clusters, in batches small
+    enough that their (C, C) tiles fit ``_KNN_TILE_BYTES``. Small clusters
+    take one vmap; large ones (C in the thousands, where a whole vmap would
+    need K·C² floats at once) run as a ``lax.map`` of vmapped batches."""
+    Kc, C = valid.shape
+    batch = max(1, _KNN_TILE_BYTES // (4 * C * C))
+    one = lambda xb, vb: _cluster_knn_jit(xb, vb, k, impl)
+    if batch >= Kc:
+        return jax.vmap(one)(x_blocks, valid)
+    return jax.lax.map(lambda a: one(*a), (x_blocks, valid), batch_size=batch)
 
 
 def query_cluster_knn(
@@ -142,7 +156,7 @@ def cluster_knn_batch_sharded(mesh, axis: str, x_blocks, counts, k: int, impl=No
     parallel, so the only data movement is placing ``x_blocks`` row-sharded.
     On a 1-device mesh this is the local vmap, bit-for-bit.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.kernels import registry
@@ -169,9 +183,9 @@ def cluster_knn_batch_sharded(mesh, axis: str, x_blocks, counts, k: int, impl=No
         mesh=mesh,
         in_specs=(P(axis, None, None), P(axis, None)),
         out_specs=(P(axis, None, None), P(axis, None, None)),
-        check_rep=False,
+        check_vma=False,
     )
     def run(xb_l, vb_l):
-        return jax.vmap(lambda a, b: _cluster_knn_jit(a, b, k, resolved))(xb_l, vb_l)
+        return _knn_over_clusters(xb_l, vb_l, k, resolved)
 
     return run(xb, vb)

@@ -5,8 +5,8 @@ in the spec's small tile grid on synthesized inputs and records the winner:
 
 * in-process  — a dict, so a jitted trace asks at most once per bucket;
 * on disk     — JSON at ``$REPRO_TUNE_CACHE`` (default
-  ``~/.cache/repro/kernel_tune.json``), so winners survive across runs and
-  can be shipped with a deployment.
+  ``<checkout>/.kernel_tune/kernel_tune.json``), so winners survive across
+  runs and can be shipped with a deployment.
 
 v2 cache semantics:
 
@@ -24,11 +24,15 @@ v2 cache semantics:
   next store; stores are read-modify-write with an atomic replace, so two
   racing processes each leave a valid file (last writer wins).
 
-The sweep runs *eagerly* on freshly synthesized concrete inputs (from
-``spec.make_inputs``), which makes it legal to trigger from inside a jit
-trace: tracers only contribute their static shape signature, never data.
-``sweep(..., report=True)`` additionally returns every candidate's wall
-time for the achieved-vs-roofline report in ``benchmarks/kernel_micro.py``.
+The sweep times candidates on freshly synthesized concrete inputs (from
+``spec.make_inputs``), so it runs only eagerly — e.g. from
+``benchmarks/kernel_micro.py --autotune``. Under a ``jit`` trace every
+operation is staged, so a sweep there would time the tracer, not the
+kernel: :func:`tiles_for` then serves the cached winner or the spec's
+default tiles and never sweeps. A sweep in which every candidate fails
+raises the first candidate's error. ``sweep(..., report=True)``
+additionally returns every candidate's wall time for the
+achieved-vs-roofline report in ``benchmarks/kernel_micro.py``.
 
 Enablement policy (``REPRO_AUTOTUNE``): "1" forces tuning on, "0" forces it
 off; unset ⇒ tune only when the Pallas path actually compiles (i.e. not in
@@ -50,6 +54,7 @@ from typing import Mapping, Optional
 import jax
 
 from repro.kernels.registry import KernelSpec, ShapeSig, backend, interpret_default
+from repro.runtime import CHECKOUT
 
 CACHE_VERSION = 2
 
@@ -61,8 +66,7 @@ _SWEEP_REPS = 3  # timed reps per candidate (after one compile/warmup call)
 
 def cache_path() -> str:
     return os.environ.get(
-        "REPRO_TUNE_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro", "kernel_tune.json"),
+        "REPRO_TUNE_CACHE", os.path.join(CHECKOUT, ".kernel_tune", "kernel_tune.json")
     )
 
 
@@ -232,23 +236,26 @@ def sweep(
     Runs eagerly on synthesized inputs — never touches caller data.
     ``report=True`` adds a ``"candidates"`` list (every candidate's tiles
     and wall time) for roofline reporting; it is stripped before disk.
+    A candidate that fails is skipped; when all fail, the first error is
+    raised.
     """
     if interpret is None:
         interpret = interpret_default()
     args = spec.make_inputs(jax.random.key(0), sig)
-    results = []
+    results, first_err = [], None
     for tiles in spec.tile_candidates:
         try:
             us = _time_candidate(spec, args, tiles, interpret)
-        except Exception:  # noqa: BLE001 — invalid tiling for this shape
+        except Exception as e:  # noqa: BLE001 — a tiling this shape cannot take
+            first_err = first_err or e
             continue
         results.append((us, dict(tiles)))
     if not results:
-        entry = {"tiles": dict(spec.tiles_for_backend(backend())), "us": None}
-    else:
-        us, tiles = min(results, key=lambda r: r[0])
-        entry = {"tiles": tiles, "us": us, "n_candidates": len(results)}
-    entry["src"] = source_hash(spec)
+        raise RuntimeError(
+            f"autotune: every tile candidate of {spec.name!r} failed at {sig}"
+        ) from first_err
+    us, tiles = min(results, key=lambda r: r[0])
+    entry = {"tiles": tiles, "us": us, "n_candidates": len(results), "src": source_hash(spec)}
     if report:
         entry["candidates"] = [{"tiles": t, "us": u} for u, t in results]
     return entry
@@ -264,22 +271,27 @@ def record(spec: KernelSpec, sig: ShapeSig, entry: dict) -> None:
     _store_disk(key, entry)
 
 
-def tiles_for(spec: KernelSpec, sig: ShapeSig) -> Mapping[str, int]:
+def tiles_for(spec: KernelSpec, sig: ShapeSig, *, tracing: bool = False) -> Mapping[str, int]:
     """The dispatcher's entry point: cached winner, else sweep, else defaults.
 
     Keys — and sweeps — at the *bucketed* signature, so every shape in a
-    bucket shares one entry and one sweep.
+    bucket shares one entry and one sweep. ``tracing=True`` (the caller is
+    being staged under ``jit``) never sweeps: it serves the cached winner
+    or the defaults, and leaves the entry open for a later eager sweep.
     """
     back = backend()
     key = cache_key(spec.name, back, sig)
     _load_disk()
     entry = _memory_cache.get(key)
-    if entry is None:
-        if autotune_enabled():
-            entry = sweep(spec, bucket_sig(sig))
-            if entry.get("us") is not None:  # a failed sweep (every candidate
-                _store_disk(key, entry)  # errored) must not poison the disk
-        else:  # cache — retry next process
-            entry = {"tiles": dict(spec.tiles_for_backend(back)), "us": None}
-        _memory_cache[key] = entry
+    if entry is not None:
+        return entry["tiles"]
+    defaults = {"tiles": dict(spec.tiles_for_backend(back)), "us": None}
+    if tracing:
+        return defaults["tiles"]
+    if autotune_enabled():
+        entry = sweep(spec, bucket_sig(sig))
+        _store_disk(key, entry)
+    else:
+        entry = defaults
+    _memory_cache[key] = entry
     return entry["tiles"]

@@ -7,9 +7,13 @@ the reduction means the (B, K) intermediate never touches HBM — only
 θ (d×B), μ (d×K), w (K) stream in and s (B) streams out; arithmetic
 intensity is ~K/2 flops/byte, comfortably compute-bound on the VPU.
 
-Layout note (TPU adaptation): positions are passed transposed, (d, B) and
-(d, K) with d = 2, so the minor (lane) axis is the large one; the tiny d
-axis sits on sublanes. The (bb, bk) working tile lives in VMEM/VREGs only.
+Layout note (TPU adaptation): head positions are passed transposed, (d, B)
+with d = 2, so the minor (lane) axis is the large one; the tiny d axis sits
+on sublanes. The means stay (K, d) and the weights (K, 1), so the (bk, bb)
+working tile — cells on sublanes, heads on lanes — is built from a θ row
+broadcast down sublanes and a μ column broadcast across lanes, and its K
+reduction lands lane-major like the output. The tile lives in VMEM/VREGs
+only.
 """
 
 from __future__ import annotations
@@ -22,12 +26,18 @@ from jax.experimental import pallas as pl
 
 
 def _dist2_tile(th, mu, d):
-    """th (d, bb), mu (d, bk) → (bb, bk) squared distances (d unrolled)."""
+    """th (d, bb), mu (bk, d) → (bk, bb) squared distances (d unrolled)."""
     acc = None
     for dd in range(d):
-        diff = th[dd, :, None] - mu[dd, None, :]
+        diff = th[dd : dd + 1, :] - mu[:, dd : dd + 1]
         acc = diff * diff if acc is None else acc + diff * diff
     return acc
+
+
+def _not_own(own_ref, kstep, bk, bb):
+    """(bk, bb) mask: 1 where cell kstep·bk + row is not the head's own."""
+    r_ids = kstep * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, bb), 0)
+    return (own_ref[...] != r_ids).astype(jnp.float32)
 
 
 def _fwd_kernel(theta_ref, means_ref, w_ref, own_ref, out_ref, *, d, bk):
@@ -38,14 +48,10 @@ def _fwd_kernel(theta_ref, means_ref, w_ref, own_ref, out_ref, *, d, bk):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     th = theta_ref[...]  # (d, bb)
-    mu = means_ref[...]  # (d, bk)
-    q = 1.0 / (1.0 + _dist2_tile(th, mu, d))  # (bb, bk)
-    bb = th.shape[1]
-    r_ids = kstep * bk + jax.lax.broadcasted_iota(jnp.int32, (bb, bk), 1)
-    own = own_ref[...]  # (1, bb)
-    mask = (own[0, :, None] != r_ids).astype(jnp.float32)
-    w = w_ref[...][0, None, :]  # (1, bk)
-    out_ref[0, :] += jnp.sum(q * w * mask, axis=1)
+    q = 1.0 / (1.0 + _dist2_tile(th, means_ref[...], d))  # (bk, bb)
+    mask = _not_own(own_ref, kstep, bk, th.shape[1])
+    w = w_ref[...]  # (bk, 1)
+    out_ref[...] += jnp.sum(q * w * mask, axis=0, keepdims=True)
 
 
 def _bwd_kernel(theta_ref, means_ref, w_ref, own_ref, gbar_ref, gout_ref, *, d, bk):
@@ -55,18 +61,16 @@ def _bwd_kernel(theta_ref, means_ref, w_ref, own_ref, gbar_ref, gout_ref, *, d, 
     def _init():
         gout_ref[...] = jnp.zeros_like(gout_ref)
 
-    th = theta_ref[...]
-    mu = means_ref[...]
-    q = 1.0 / (1.0 + _dist2_tile(th, mu, d))
-    bb = th.shape[1]
-    r_ids = kstep * bk + jax.lax.broadcasted_iota(jnp.int32, (bb, bk), 1)
-    own = own_ref[...]  # (1, bb)
-    mask = (own[0, :, None] != r_ids).astype(jnp.float32)
-    factor = w_ref[...][0, None, :] * mask * q * q  # (bb, bk)
-    gbar = gbar_ref[...][0, :]  # (bb,)
+    th = theta_ref[...]  # (d, bb)
+    mu = means_ref[...]  # (bk, d)
+    q = 1.0 / (1.0 + _dist2_tile(th, mu, d))  # (bk, bb)
+    mask = _not_own(own_ref, kstep, bk, th.shape[1])
+    factor = w_ref[...] * mask * q * q  # (bk, bb)
+    gbar = gbar_ref[...]  # (1, bb)
     for dd in range(d):
-        diff = th[dd, :, None] - mu[dd, None, :]
-        gout_ref[dd, :] += -2.0 * gbar * jnp.sum(factor * diff, axis=1)
+        diff = th[dd : dd + 1, :] - mu[:, dd : dd + 1]
+        gsum = jnp.sum(factor * diff, axis=0, keepdims=True)
+        gout_ref[dd : dd + 1, :] += -2.0 * gbar * gsum
 
 
 def _grids(B, K, bb, bk):
@@ -74,10 +78,10 @@ def _grids(B, K, bb, bk):
     return (B // bb, K // bk)
 
 
-def cauchy_mean_fwd_pallas(theta_t, means_t, w, own, *, bb=512, bk=1024, interpret=True):
-    """theta_t (d, B), means_t (d, K), w (1, K), own (1, B) → s (1, B)."""
+def cauchy_mean_fwd_pallas(theta_t, means, w, own, *, bb=512, bk=1024, interpret=True):
+    """theta_t (d, B), means (K, d), w (K, 1), own (1, B) → s (1, B)."""
     d, B = theta_t.shape
-    K = means_t.shape[1]
+    K = means.shape[0]
     bb, bk = min(bb, B), min(bk, K)
     grid = _grids(B, K, bb, bk)
     return pl.pallas_call(
@@ -85,20 +89,20 @@ def cauchy_mean_fwd_pallas(theta_t, means_t, w, own, *, bb=512, bk=1024, interpr
         grid=grid,
         in_specs=[
             pl.BlockSpec((d, bb), lambda i, kk: (0, i)),
-            pl.BlockSpec((d, bk), lambda i, kk: (0, kk)),
-            pl.BlockSpec((1, bk), lambda i, kk: (0, kk)),
+            pl.BlockSpec((bk, d), lambda i, kk: (kk, 0)),
+            pl.BlockSpec((bk, 1), lambda i, kk: (kk, 0)),
             pl.BlockSpec((1, bb), lambda i, kk: (0, i)),
         ],
         out_specs=pl.BlockSpec((1, bb), lambda i, kk: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, B), jnp.float32),
         interpret=interpret,
-    )(theta_t, means_t, w, own)
+    )(theta_t, means, w, own)
 
 
-def cauchy_mean_bwd_pallas(theta_t, means_t, w, own, gbar, *, bb=512, bk=1024, interpret=True):
+def cauchy_mean_bwd_pallas(theta_t, means, w, own, gbar, *, bb=512, bk=1024, interpret=True):
     """Adds gbar: returns gθ (d, B)."""
     d, B = theta_t.shape
-    K = means_t.shape[1]
+    K = means.shape[0]
     bb, bk = min(bb, B), min(bk, K)
     grid = _grids(B, K, bb, bk)
     return pl.pallas_call(
@@ -106,12 +110,12 @@ def cauchy_mean_bwd_pallas(theta_t, means_t, w, own, gbar, *, bb=512, bk=1024, i
         grid=grid,
         in_specs=[
             pl.BlockSpec((d, bb), lambda i, kk: (0, i)),
-            pl.BlockSpec((d, bk), lambda i, kk: (0, kk)),
-            pl.BlockSpec((1, bk), lambda i, kk: (0, kk)),
+            pl.BlockSpec((bk, d), lambda i, kk: (kk, 0)),
+            pl.BlockSpec((bk, 1), lambda i, kk: (kk, 0)),
             pl.BlockSpec((1, bb), lambda i, kk: (0, i)),
             pl.BlockSpec((1, bb), lambda i, kk: (0, i)),
         ],
         out_specs=pl.BlockSpec((d, bb), lambda i, kk: (0, i)),
         out_shape=jax.ShapeDtypeStruct((d, B), jnp.float32),
         interpret=interpret,
-    )(theta_t, means_t, w, own, gbar)
+    )(theta_t, means, w, own, gbar)

@@ -35,8 +35,8 @@ def _build_op(bb_max: int, bk_max: int, interpret: bool):
         B = theta_i.shape[0]
         bb, bk = min(bb_max, max(B, 8)), min(bk_max, max(means.shape[0], 128))
         th = _pad_minor(theta_i.astype(jnp.float32).T, bb)  # (d, B')
-        mu = _pad_minor(means.astype(jnp.float32).T, bk)  # (d, K')
-        w = _pad_minor(cell_w.astype(jnp.float32)[None, :], bk)  # (1, K') pad w=0
+        mu = _pad_minor(means.astype(jnp.float32).T, bk).T  # (K', d)
+        w = _pad_minor(cell_w.astype(jnp.float32)[None, :], bk).T  # (K', 1) pad w=0
         own = _pad_minor(own_cell.astype(jnp.int32)[None, :], bb, fill=-1)
         return th, mu, w, own, bb, bk, B
 

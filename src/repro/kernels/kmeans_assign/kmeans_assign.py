@@ -27,7 +27,9 @@ def _kernel(x_ref, c_ref, arg_ref, min_ref, *, bk):
     x = x_ref[...]  # (bn, D)
     c = c_ref[...]  # (bk, D)
     cross = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, c, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,  # f32 operands, f32 distances
+        preferred_element_type=jnp.float32,
     )
     d2 = (
         jnp.sum(jnp.square(x), axis=1, keepdims=True)

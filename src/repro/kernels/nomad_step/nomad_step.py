@@ -11,9 +11,12 @@ cell weights stream in; loss (1×B) and m (1×B, the backward's residual)
 stream out.
 
 Layout (same TPU adaptation as ``cauchy_mean``/``frozen_attract``):
-everything crosses the kernel transposed with the large B (and K) axis on
+per-head tensors cross the kernel transposed with the large B axis on
 lanes; the tiny static k, S and d axes are flattened as (k·d, B) rows
-s·d + dd and fully unrolled.
+s·d + dd and fully unrolled. The means arrive untransposed, μ (K, d) and
+cell weights (K, 1), so the (bk, bb) mean-term tile puts cells on
+sublanes and heads on lanes: θ rows broadcast down sublanes, μ columns
+across lanes, and the K reduction lands lane-major like m itself.
 
 Schedule (grid = (B//bb, K//bk), kstep = program_id(1) iterates fastest):
 
@@ -36,14 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _dist2_tile(th, mu, d):
-    """th (d, bb), mu (d, bk) → (bb, bk) squared distances (d unrolled)."""
-    acc = None
-    for dd in range(d):
-        diff = th[dd, :, None] - mu[dd, None, :]
-        acc = diff * diff if acc is None else acc + diff * diff
-    return acc
+from repro.kernels.cauchy_mean.cauchy_mean import _dist2_tile, _not_own
 
 
 def _flat_dist2(th, flat_ref, j, d):
@@ -68,14 +64,10 @@ def _fwd_kernel(
         loss_ref[...] = jnp.zeros_like(loss_ref)
 
     th = th_ref[...]  # (d, bb)
-    mu = mu_ref[...]  # (d, bk)
-    q = 1.0 / (1.0 + _dist2_tile(th, mu, d))  # (bb, bk)
-    bb = th.shape[1]
-    r_ids = kstep * bk + jax.lax.broadcasted_iota(jnp.int32, (bb, bk), 1)
-    own = own_ref[...]  # (1, bb)
-    mask = (own[0, :, None] != r_ids).astype(jnp.float32)
-    w = cw_ref[...][0, None, :]  # (1, bk)
-    m_ref[0, :] += jnp.sum(q * w * mask, axis=1)  # online M̃ accumulation
+    q = 1.0 / (1.0 + _dist2_tile(th, mu_ref[...], d))  # (bk, bb)
+    mask = _not_own(own_ref, kstep, bk, th.shape[1])
+    w = cw_ref[...]  # (bk, 1)
+    m_ref[...] += jnp.sum(q * w * mask, axis=0, keepdims=True)  # online M̃
 
     @pl.when(kstep == nk - 1)
     def _finish():
@@ -136,16 +128,13 @@ def _bwd_kernel(
             gi_ref[dd, :] = 2.0 * gbar * gi[dd]
 
     # mean-term gradient of this K tile, accumulated online into g_i
-    mu = mu_ref[...]
-    q = 1.0 / (1.0 + _dist2_tile(th, mu, d))
-    bb = th.shape[1]
-    r_ids = kstep * bk + jax.lax.broadcasted_iota(jnp.int32, (bb, bk), 1)
-    own = own_ref[...]
-    mask = (own[0, :, None] != r_ids).astype(jnp.float32)
-    factor = cw_ref[...][0, None, :] * mask * q * q  # (bb, bk)
+    mu = mu_ref[...]  # (bk, d)
+    q = 1.0 / (1.0 + _dist2_tile(th, mu, d))  # (bk, bb)
+    mask = _not_own(own_ref, kstep, bk, th.shape[1])
+    factor = cw_ref[...] * mask * q * q  # (bk, bb)
     for dd in range(d):
-        diff = th[dd, :, None] - mu[dd, None, :]
-        gi_ref[dd, :] += -2.0 * gbar * G * jnp.sum(factor * diff, axis=1)
+        diff = th[dd : dd + 1, :] - mu[:, dd : dd + 1]
+        gi_ref[dd, :] += -2.0 * gbar * G * jnp.sum(factor * diff, axis=0)
 
 
 def _grids(B, K, bb, bk):
@@ -156,11 +145,11 @@ def _grids(B, K, bb, bk):
 def nomad_step_fwd_pallas(
     th, pos, pw, neg, nw, mu, cw, own, *, bb=512, bk=1024, interpret=True
 ):
-    """th (d,B), pos (k·d,B), pw (k,B), neg (S·d,B), nw (S,B), mu (d,K),
-    cw (1,K), own (1,B) → (loss (1,B), m (1,B))."""
+    """th (d,B), pos (k·d,B), pw (k,B), neg (S·d,B), nw (S,B), mu (K,d),
+    cw (K,1), own (1,B) → (loss (1,B), m (1,B))."""
     d, B = th.shape
     k, s = pw.shape[0], nw.shape[0]
-    K = mu.shape[1]
+    K = mu.shape[0]
     bb, bk = min(bb, B), min(bk, K)
     grid = _grids(B, K, bb, bk)
     return pl.pallas_call(
@@ -172,8 +161,8 @@ def nomad_step_fwd_pallas(
             pl.BlockSpec((k, bb), lambda i, kk: (0, i)),
             pl.BlockSpec((s * d, bb), lambda i, kk: (0, i)),
             pl.BlockSpec((s, bb), lambda i, kk: (0, i)),
-            pl.BlockSpec((d, bk), lambda i, kk: (0, kk)),
-            pl.BlockSpec((1, bk), lambda i, kk: (0, kk)),
+            pl.BlockSpec((bk, d), lambda i, kk: (kk, 0)),
+            pl.BlockSpec((bk, 1), lambda i, kk: (kk, 0)),
             pl.BlockSpec((1, bb), lambda i, kk: (0, i)),
         ],
         out_specs=[
@@ -195,7 +184,7 @@ def nomad_step_bwd_pallas(
     g_pos (k·d,B), g_neg (S·d,B))."""
     d, B = th.shape
     k, s = pw.shape[0], nw.shape[0]
-    K = mu.shape[1]
+    K = mu.shape[0]
     bb, bk = min(bb, B), min(bk, K)
     grid = _grids(B, K, bb, bk)
     return pl.pallas_call(
@@ -207,8 +196,8 @@ def nomad_step_bwd_pallas(
             pl.BlockSpec((k, bb), lambda i, kk: (0, i)),
             pl.BlockSpec((s * d, bb), lambda i, kk: (0, i)),
             pl.BlockSpec((s, bb), lambda i, kk: (0, i)),
-            pl.BlockSpec((d, bk), lambda i, kk: (0, kk)),
-            pl.BlockSpec((1, bk), lambda i, kk: (0, kk)),
+            pl.BlockSpec((bk, d), lambda i, kk: (kk, 0)),
+            pl.BlockSpec((bk, 1), lambda i, kk: (kk, 0)),
             pl.BlockSpec((1, bb), lambda i, kk: (0, i)),
             pl.BlockSpec((1, bb), lambda i, kk: (0, i)),
             pl.BlockSpec((1, bb), lambda i, kk: (0, i)),

@@ -50,8 +50,8 @@ def _build_op(bb_max: int, bk_max: int, interpret: bool):
             jnp.transpose(theta_neg.astype(jnp.float32), (1, 2, 0)).reshape(S * d, B), bb
         )
         nw = _pad_minor(neg_w.astype(jnp.float32).T, bb)  # (S, B') pad w=0
-        mu = _pad_minor(means.astype(jnp.float32).T, bk)  # (d, K')
-        cw = _pad_minor(cell_w.astype(jnp.float32)[None, :], bk)  # (1, K') pad w=0
+        mu = _pad_minor(means.astype(jnp.float32).T, bk).T  # (K', d)
+        cw = _pad_minor(cell_w.astype(jnp.float32)[None, :], bk).T  # (K', 1) pad w=0
         own = _pad_minor(own_cell.astype(jnp.int32)[None, :], bb, fill=-1)
         return th, pos, pw, neg, nw, mu, cw, own, bb, bk, B
 

@@ -30,7 +30,9 @@ def _kernel(x_ref, y_ref, out_ref, *, n_d_steps: int):
     y = y_ref[...]  # (bm, bd)
     # accumulate ‖x‖² + ‖y‖² − 2 x yᵀ piecewise over D
     cross = jax.lax.dot_general(
-        x, y, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, y, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,  # f32 operands, f32 distances
+        preferred_element_type=jnp.float32,
     )
     x2 = jnp.sum(jnp.square(x), axis=1, keepdims=True)  # (bn, 1)
     y2 = jnp.sum(jnp.square(y), axis=1, keepdims=True).T  # (1, bm)

@@ -193,7 +193,8 @@ def dispatch(name: str, *args, impl=None, tiles: Optional[Mapping[str, int]] = N
     """Run kernel ``name`` on ``args`` through the resolved implementation.
 
     Safe to call under ``jit``/``grad``: resolution happens at trace time
-    (implementation choice and tile sizes are static w.r.t. the trace).
+    (implementation choice and tile sizes are static w.r.t. the trace), and
+    a traced call never starts an autotune sweep.
     """
     spec = get(name)
     if resolve(name, impl) == "jnp":
@@ -201,7 +202,8 @@ def dispatch(name: str, *args, impl=None, tiles: Optional[Mapping[str, int]] = N
     if tiles is None:
         from repro.kernels import autotune
 
-        tiles = autotune.tiles_for(spec, shape_sig(args))
+        tracing = any(isinstance(a, jax.core.Tracer) for a in args)
+        tiles = autotune.tiles_for(spec, shape_sig(args), tracing=tracing)
     return spec.pallas(*args, tiles=tiles, interpret=interpret_default())
 
 
@@ -233,7 +235,10 @@ def validate(
     if interpret is None:
         interpret = interpret_default()
     got = spec.pallas(*args, tiles=tiles, interpret=interpret)
-    want = spec.ref(*args)
+    # the oracle is plain float32 math: on a TPU, XLA's default f32 matmul
+    # takes one bf16 pass, so pin full precision (a no-op on CPU)
+    with jax.default_matmul_precision("highest"):
+        want = spec.ref(*args)
     if spec.oracle_check is not None:
         spec.oracle_check(args, got, want)
         return got, want

@@ -22,7 +22,9 @@ exactly like it crosses devices. This module is the host-side glue:
   same ``--coordinator host:port``; or ``--spawn K`` to launch K local
   worker processes against an automatically chosen local coordinator
   port (the CI/test harness, and the quickest way to try 2 processes on
-  one machine). Every process must see the same data — pass ``--store``
+  one machine). The spawn is a CPU simulation: on an accelerator host it
+  refuses more workers than the host has chips. Every process must see
+  the same data — pass ``--store``
   (a shared-filesystem embedding store) for anything big; each process
   then reads only its own row range of it (the ``"distributed"`` index
   build), so no process ever holds all N rows.
@@ -141,8 +143,42 @@ def parse_args(argv=None):
     return ap.parse_args(argv)
 
 
+def local_platform() -> tuple:
+    """(platform, local device count) of this host.
+
+    Asked of a short-lived child process: a process that has touched JAX
+    holds the chip, and the workers spawned after it would find none.
+    ``JAX_PLATFORMS=cpu`` needs no probe.
+    """
+    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
+        return "cpu", 0
+    probe = "import jax; print(jax.default_backend(), jax.local_device_count())"
+    out = subprocess.run(
+        [sys.executable, "-c", probe], capture_output=True, text=True, check=True
+    )
+    platform, count = out.stdout.split()[-2:]
+    return platform, int(count)
+
+
+def check_spawn(n_workers: int, platform: str, n_chips: int) -> None:
+    """The K-process spawn simulates a cluster on CPU devices. On an
+    accelerator host a chip serves one process, so more workers than chips
+    cannot all start: refuse before starting any."""
+    if platform != "cpu" and n_workers > n_chips:
+        raise ValueError(
+            f"--spawn {n_workers}: this {platform} host has {n_chips} chip(s) "
+            "and a chip serves one process; run one process, which drives "
+            "every local chip"
+        )
+
+
 def _spawn_workers(args, argv) -> int:
     """``--spawn K``: run K local workers against a local coordinator."""
+    try:
+        check_spawn(args.spawn, *local_platform())
+    except ValueError as e:
+        print(e, file=sys.stderr, flush=True)
+        return 2
     port = pick_free_port()
     strip = {"--spawn": 1}
     child_common: list = []
@@ -177,6 +213,9 @@ def main(argv=None) -> int:
             f"--xla_force_host_platform_device_count={args.host_devices}"
         )
 
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache()
     t_start = time.time()
     try:
         initialize_distributed(

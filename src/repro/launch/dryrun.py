@@ -33,6 +33,10 @@ import traceback
 import numpy as np
 
 
+# the production meshes are TPU v5e pods; roofline terms use its peaks
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
 def _mesh_tag(multi_pod: bool) -> str:
     return "pod2x16x16" if multi_pod else "pod16x16"
 
@@ -46,7 +50,7 @@ def run_lm_cell(arch_name: str, shape_name: str, multi_pod: bool, save_hlo: str 
     from repro.launch.sharding import step_shardings
     from repro.models import steps as steps_lib
     from repro.optim import AdamW, warmup_cosine
-    from repro.roofline.analysis import model_flops, roofline_terms
+    from repro.roofline.analysis import model_flops, peaks_for, roofline_terms
     from repro.roofline.hlo_cost import analyze_hlo
 
     cfg = get_arch(arch_name)
@@ -127,7 +131,7 @@ def run_lm_cell(arch_name: str, shape_name: str, multi_pod: bool, save_hlo: str 
     rep = analyze_hlo(hlo)
     mf = model_flops(cfg, shape)
     # per-device useful flops → terms; model_flops is global
-    terms = roofline_terms(rep, n_chips, mf)
+    terms = roofline_terms(rep, n_chips, mf, peaks_for(TARGET_DEVICE_KIND))
     if save_hlo:
         os.makedirs(save_hlo, exist_ok=True)
         with open(os.path.join(save_hlo, f"{arch_name}__{shape_name}__{_mesh_tag(multi_pod)}.hlo"), "w") as f:
@@ -183,7 +187,12 @@ def run_nomad_cell(workload: str, multi_pod: bool, save_hlo: str | None):
     from repro.configs import get_nomad
     from repro.core.distributed import make_sharded_epoch_fn
     from repro.launch.mesh import make_production_mesh
-    from repro.roofline.analysis import nomad_analytic_terms, nomad_model_flops, roofline_terms
+    from repro.roofline.analysis import (
+        nomad_analytic_terms,
+        nomad_model_flops,
+        peaks_for,
+        roofline_terms,
+    )
     from repro.roofline.hlo_cost import analyze_hlo
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -245,7 +254,7 @@ def run_nomad_cell(workload: str, multi_pod: bool, save_hlo: str | None):
         cfg.n_points, cfg.batch_size * n_chips, cfg.n_neighbors,
         cfg.n_exact_negatives, cfg.n_clusters, steps,
     )
-    terms = roofline_terms(rep, n_chips, mf)
+    terms = roofline_terms(rep, n_chips, mf, peaks_for(TARGET_DEVICE_KIND))
     if save_hlo:
         os.makedirs(save_hlo, exist_ok=True)
         with open(os.path.join(save_hlo, f"{workload}__epoch__{_mesh_tag(multi_pod)}.hlo"), "w") as f:
@@ -263,7 +272,7 @@ def run_nomad_cell(workload: str, multi_pod: bool, save_hlo: str | None):
         "hierarchical": bool(cfg.hierarchical and multi_pod),
         # kernel-true terms: the HLO memory term is inflated by the Pallas
         # interpret-mode tile boundaries (VMEM-resident on a real TPU)
-        "analytic_terms": nomad_analytic_terms(cfg, n_chips, steps),
+        "analytic_terms": nomad_analytic_terms(cfg, n_chips, steps, peaks_for(TARGET_DEVICE_KIND)),
         "memory": {
             "argument_bytes": ma.argument_size_in_bytes,
             "output_bytes": ma.output_size_in_bytes,

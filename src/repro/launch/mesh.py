@@ -10,27 +10,21 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """Pin the pre-0.9 Auto axis-type behaviour where the API exists.
-
-    ``jax.sharding.AxisType`` only appears in jax >= 0.5; older releases
-    have exactly that behaviour already, so the kwarg is simply omitted.
-    """
-    if hasattr(jax.sharding, "AxisType"):
-        return {"axis_types": (jax.sharding.AxisType.Auto,) * n_axes}
-    return {}
+def _auto_axes(n_axes: int) -> tuple:
+    """Every axis ``Auto``: sharding propagates through jit as before 0.9."""
+    return (jax.sharding.AxisType.Auto,) * n_axes
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per pod; 2 pods = 512 chips when ``multi_pod``."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto_axes(len(axes)))
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh with the pre-0.9 Auto axis-type behaviour pinned."""
-    return jax.make_mesh(tuple(shape), tuple(axes), **_axis_type_kwargs(len(axes)))
+    """Arbitrary mesh with every axis ``Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axes), axis_types=_auto_axes(len(axes)))
 
 
 def flat_mesh(axis: str = "data", devs=None):

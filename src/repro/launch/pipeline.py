@@ -23,7 +23,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -57,7 +57,7 @@ def gpipe(
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     def run(stage_params, x_micro):
         sp = jax.tree.map(lambda a: a[0], stage_params)  # this stage's params

@@ -27,6 +27,7 @@ def main() -> int:
     from repro.core.nomad import NomadProjection
     from repro.data.synthetic import gaussian_mixture
     from repro.index.ann import build_index
+    from repro.launch.mesh import make_mesh
     from repro.index.kmeans import kmeans_fit_sharded, lsh_init_centroids, assign_jnp, _m_step
     from repro.metrics import neighborhood_preservation, random_triplet_accuracy
 
@@ -47,7 +48,7 @@ def main() -> int:
     ref = NomadProjection(cfg, strategy="local").fit(x, index=index)
     np_ref = neighborhood_preservation(x, ref.embedding, k=10, n_queries=400)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     dist = NomadProjection(
         cfg, strategy="sharded", mesh=mesh, shard_axes=("data", "model")
     ).fit(x, index=index)
@@ -83,7 +84,7 @@ def main() -> int:
     print("fit_distributed shim: OK (DeprecationWarning emitted)")
 
     # --- 3. hierarchical multi-pod ---------------------------------------------
-    mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh3 = make_mesh((2, 2, 2), ("pod", "data", "model"))
     hier = NomadProjection(
         cfg,
         strategy="hierarchical",
@@ -106,7 +107,7 @@ def main() -> int:
     # --- 4. distributed K-means ≡ reference EM ---------------------------------
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    mesh1 = jax.make_mesh((8,), ("data",))
+    mesh1 = make_mesh((8,), ("data",))
     xs = jax.device_put(jnp.asarray(x), NamedSharding(mesh1, P("data", None)))
     cents_d = kmeans_fit_sharded(jax.random.key(0), xs, 16, mesh1, "data", n_iters=5)
     cents = lsh_init_centroids(jax.random.key(0), jnp.asarray(x), 16)

@@ -65,6 +65,9 @@ def main(argv=None) -> int:
     from repro.core.strategy import FitCallbacks
     from repro.data.synthetic import hierarchical_mixture
     from repro.launch.mesh import make_mesh
+    from repro.runtime import enable_compile_cache
+
+    enable_compile_cache()
 
     cfg = get_nomad(args.workload)
     if args.n_points:

@@ -26,7 +26,8 @@ def _topk_neighbors(queries: jnp.ndarray, data: jnp.ndarray, k: int, block: int 
     n = data.shape[0]
     for start in range(0, n, block):
         db = data[start : start + block]
-        d2 = q2 + jnp.sum(jnp.square(db), -1)[None, :] - 2.0 * queries @ db.T
+        cross = jnp.matmul(queries, db.T, precision=jax.lax.Precision.HIGHEST)
+        d2 = q2 + jnp.sum(jnp.square(db), -1)[None, :] - 2.0 * cross
         d2 = jnp.maximum(d2, 0.0)
         cat_d = jnp.concatenate([best_d, d2], axis=1)
         cat_i = jnp.concatenate(

@@ -365,7 +365,7 @@ def set_decode_context(mesh, batch_axes, s_axes) -> None:
 def attend_decode_sharded(q, k_cache, v_cache, q_pos, k_pos, valid, *, window: int = 0):
     import functools
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh, baxes, saxes = _DECODE_CTX
@@ -382,7 +382,7 @@ def attend_decode_sharded(q, k_cache, v_cache, q_pos, k_pos, valid, *, window: i
             P(baxes, saxes),
         ),
         out_specs=P(baxes, None, None, None),
-        check_rep=False,
+        check_vma=False,
     )
     def block(q, kc, vc, qp, kp, vd):
         o, m, l = _decode_local(q, kc, vc, qp, kp, vd, window)

@@ -231,7 +231,7 @@ def _ep_weight_specs(cfg, msize: int, fsdp):
 def moe_ep(p: MoEParams, x: jax.Array, cfg):
     import functools
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh, dp_axes, token_axes, maxis, stationary = _EP_MESH
@@ -258,7 +258,7 @@ def moe_ep(p: MoEParams, x: jax.Array, cfg):
         mesh=mesh,
         in_specs=(x_spec, P(), gu_spec, gu_spec, d_spec),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )
     def block(x_loc, router, wg, wu, wd):
         B_loc, S, _ = x_loc.shape

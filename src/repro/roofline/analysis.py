@@ -1,7 +1,8 @@
 """Roofline-term computation (assignment §Roofline).
 
-Hardware constants are TPU v5e-class (the stated target):
-  197 TFLOP/s bf16 per chip, 819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware peaks come from :data:`PEAKS`, keyed by the ``device_kind`` JAX
+reports; every caller names the device it means, and a device that is not
+in the table is an error, never a silent default.
 
 The HLO module analysed is the per-partition program, so the parser's
 numbers are *per device*; the three terms are per-device times directly:
@@ -24,12 +25,27 @@ from typing import Optional
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.roofline.hlo_cost import CostReport
 
-HW_V5E = {
-    "peak_flops": 197e12,  # bf16 FLOP/s per chip
-    "hbm_bw": 819e9,  # B/s per chip
-    "ici_bw": 50e9,  # B/s per link
-    "name": "tpu-v5e",
+# Published per-chip peaks, keyed by ``jax.devices()[0].device_kind``.
+PEAKS = {
+    "TPU v5 lite": {
+        "peak_flops": 197e12,  # bf16 FLOP/s per chip
+        "hbm_bw": 819e9,  # B/s per chip
+        "ici_bw": 50e9,  # B/s per link (1,600 Gbit/s per chip over 4 links)
+        "name": "tpu-v5e",
+        "source": 'Google Cloud documentation, "TPU v5e"',
+    },
 }
+
+
+def peaks_for(device_kind: str) -> dict:
+    """The :data:`PEAKS` row of ``device_kind``; raises for an unknown one."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)}); add a sourced row to PEAKS"
+        ) from None
 
 
 @dataclasses.dataclass
@@ -69,7 +85,7 @@ def roofline_terms(
     report: CostReport,
     n_chips: int,
     model_fl: float,
-    hw: dict = HW_V5E,
+    hw: dict,
 ) -> RooflineTerms:
     compute_s = report.flops / hw["peak_flops"]
     memory_s = report.bytes / hw["hbm_bw"]
@@ -85,7 +101,7 @@ def roofline_terms(
     )
 
 
-def kernel_roofline(flops: float, bytes_: float, us: float, hw: dict = HW_V5E) -> dict:
+def kernel_roofline(flops: float, bytes_: float, us: float, hw: dict) -> dict:
     """Achieved-vs-roofline summary for ONE kernel call.
 
     Takes an analytic cost (``spec.cost_model(sig)``) and a measured wall
@@ -156,7 +172,7 @@ def nomad_model_flops(n_points, batch, k_nn, n_exact, n_clusters, steps) -> floa
     return 3.0 * per_step * steps
 
 
-def nomad_analytic_terms(cfg, n_chips: int, steps: int, hw: dict = HW_V5E) -> dict:
+def nomad_analytic_terms(cfg, n_chips: int, steps: int, hw: dict) -> dict:
     """Kernel-true per-device roofline terms for one NOMAD epoch.
 
     The HLO-parsed memory term is inflated on CPU: the Pallas cauchy_mean

@@ -185,7 +185,7 @@ def make_transform_fn(
     if not sharded:
         return jax.jit(body)
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     fz_specs = jax.tree_util.tree_map(
@@ -200,6 +200,6 @@ def make_transform_fn(
         mesh=mesh,
         in_specs=(fz_specs, P(axis, None), P(axis), P(axis), P(axis)),
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(sharded_body)

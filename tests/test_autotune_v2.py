@@ -15,6 +15,7 @@ from __future__ import annotations
 import json
 import threading
 
+import jax
 import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
@@ -216,3 +217,37 @@ def test_sweep_report_lists_candidates_and_disk_strips_them(tune_env):
     blob = json.loads(tune_env.read_text())
     key = autotune.cache_key(spec.name, registry.backend(), sig)
     assert "candidates" not in blob["entries"][key]  # winner only on disk
+
+
+# ---------------------------------------------------------------------------
+# Sweeps run eagerly only, and fail loud
+# ---------------------------------------------------------------------------
+
+
+def test_traced_dispatch_never_sweeps(tune_env, monkeypatch):
+    """Under jit every op is staged: a sweep there would time the tracer.
+    A traced dispatch serves the defaults; the eager one sweeps."""
+    timed = []
+    monkeypatch.setattr(
+        autotune, "_time_candidate", lambda spec, args, tiles, interpret: timed.append(args) or 1.0
+    )
+    spec = registry.get("cauchy_mean")
+    args = spec.make_inputs(jax.random.key(0), spec.check_shapes[1])
+    jax.jit(lambda *a: registry.dispatch("cauchy_mean", *a, impl="pallas"))(*args)
+    assert timed == []
+    assert not tune_env.exists()  # nothing recorded from the trace
+    registry.dispatch("cauchy_mean", *args, impl="pallas")
+    assert len(timed) == len(spec.tile_candidates)
+    assert not any(isinstance(a, jax.core.Tracer) for a in timed[0])
+
+
+def test_sweep_where_every_candidate_fails_raises_the_first_error(tune_env, monkeypatch):
+    def refuse(spec, args, tiles, interpret):
+        raise ValueError(f"tiling {dict(tiles)} refused")
+
+    monkeypatch.setattr(autotune, "_time_candidate", refuse)
+    spec = registry.get("pairwise")
+    with pytest.raises(RuntimeError, match="every tile candidate") as err:
+        autotune.tiles_for(spec, spec.check_shapes[0])
+    assert str(dict(spec.tile_candidates[0])) in str(err.value.__cause__)
+    assert not tune_env.exists()  # a failed sweep records nothing

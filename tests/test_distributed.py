@@ -76,3 +76,21 @@ def test_train_crash_restart_elastic(tmp_path):
     assert "index: cache" in r2.stdout, r2.stdout
     emb = np.load(tmp_path / "emb.npy")
     assert emb.shape == (4000, 2) and np.isfinite(emb).all()
+
+
+def test_spawn_refuses_more_workers_than_chips(monkeypatch):
+    """--spawn K simulates a cluster on CPU devices; on an accelerator host
+    a chip serves one process, so K above the chip count is refused before
+    any worker starts. The platform is steered here, in the test."""
+    from repro.launch import distributed as dist
+
+    dist.check_spawn(8, "cpu", 0)  # CPU simulation: any worker count
+    dist.check_spawn(1, "tpu", 1)
+    with pytest.raises(ValueError, match="1 chip"):
+        dist.check_spawn(2, "tpu", 1)
+
+    started = []
+    monkeypatch.setattr(dist, "local_platform", lambda: ("tpu", 4))
+    monkeypatch.setattr(dist.subprocess, "Popen", lambda *a, **k: started.append(a))
+    assert dist.main(["--spawn", "8"]) == 2
+    assert started == []

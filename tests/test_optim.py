@@ -115,7 +115,7 @@ def test_compression_error_feedback_telescopes():
 
 def test_compressed_psum_single_axis():
     import functools
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import make_mesh
@@ -124,7 +124,7 @@ def test_compressed_psum_single_axis():
     g = {"w": jnp.linspace(-2, 2, 512)}
     r = jax.tree.map(jnp.zeros_like, g)
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()), check_rep=False)
+    @functools.partial(shard_map, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()), check_vma=False)
     def run(g, r):
         return compressed_psum(g, "d", r)
 

@@ -6,15 +6,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.roofline.analysis import HW_V5E, model_flops, roofline_terms
+import pytest
+
+from repro.roofline.analysis import PEAKS, model_flops, peaks_for, roofline_terms
 from repro.roofline.hlo_cost import CostReport, analyze_hlo
-
-
-def _xla_cost(comp):
-    """``Compiled.cost_analysis()`` returns a dict on recent jax, a
-    one-element list of dicts on older releases."""
-    ca = comp.cost_analysis()
-    return ca[0] if isinstance(ca, (list, tuple)) else ca
 
 
 def test_parser_matches_xla_loop_free():
@@ -23,7 +18,7 @@ def test_parser_matches_xla_loop_free():
     sds = jax.ShapeDtypeStruct((D, D), jnp.float32)
     comp = f.lower(sds, sds, sds).compile()
     rep = analyze_hlo(comp.as_text())
-    ca = _xla_cost(comp)
+    ca = comp.cost_analysis()
     assert abs(rep.flops - ca["flops"]) / ca["flops"] < 0.02
     assert abs(rep.bytes - ca["bytes accessed"]) / ca["bytes accessed"] < 0.1
     assert abs(rep.dot_flops - 2 * 2 * D**3) / (4 * D**3) < 0.01
@@ -41,7 +36,7 @@ def test_parser_multiplies_scan_trip_count():
     rep = analyze_hlo(comp.as_text())
     want = L * 2 * D**3
     assert abs(rep.dot_flops - want) / want < 0.02
-    xla = _xla_cost(comp)["flops"]
+    xla = comp.cost_analysis()["flops"]
     assert xla < rep.flops / 3  # demonstrates XLA's undercount
 
 
@@ -97,7 +92,9 @@ def test_fixture_trip_counts_and_collectives():
 
 def test_roofline_terms_and_dominance():
     rep = CostReport(flops=197e12 * 0.01, bytes=819e9 * 0.05, collective_bytes=50e9 * 0.002)
-    t = roofline_terms(rep, n_chips=256, model_fl=197e12 * 0.01 * 256 * 0.5)
+    t = roofline_terms(
+        rep, n_chips=256, model_fl=197e12 * 0.01 * 256 * 0.5, hw=peaks_for("TPU v5 lite")
+    )
     assert abs(t.compute_s - 0.01) < 1e-9
     assert abs(t.memory_s - 0.05) < 1e-9
     assert abs(t.collective_s - 0.002) < 1e-9
@@ -105,6 +102,15 @@ def test_roofline_terms_and_dominance():
     assert abs(t.useful_ratio - 0.5) < 1e-9
     # roofline fraction: useful-compute time / bound = (0.5·0.01)/0.05
     assert abs(t.roofline_fraction - 0.1) < 1e-9
+
+
+def test_peak_table_is_keyed_by_device_kind():
+    v5e = peaks_for("TPU v5 lite")
+    assert v5e is PEAKS["TPU v5 lite"]
+    assert (v5e["peak_flops"], v5e["hbm_bw"]) == (197e12, 819e9)
+    assert "TPU v5e" in v5e["source"]
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks_for("cpu")
 
 
 def test_model_flops_sanity():
