@@ -44,7 +44,16 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import NomadConfig
 from repro.core import losses
-from repro.core.nomad import local_means, sample_in_cluster, sample_points
+from repro.core.nomad import (
+    SCOPE_GATHER,
+    SCOPE_LOSS,
+    SCOPE_MEANS,
+    SCOPE_SAMPLE,
+    local_means,
+    sample_in_cluster,
+    sample_points,
+    sgd_update,
+)
 
 
 def shard_index_and_count(mesh: Mesh, axes) -> tuple:
@@ -85,6 +94,7 @@ def make_sharded_epoch_fn(
     hierarchical = cfg.hierarchical and pod_axis is not None
     n_total = cfg.n_points
 
+    @jax.named_scope(SCOPE_MEANS)
     def gather_cells(theta_l, counts_l, counts_global, shard_off):
         """Per-refresh exchange → (cell_means, cell_w, own-exclusion base).
 
@@ -119,17 +129,19 @@ def make_sharded_epoch_fn(
         return cell_means, cell_w, own_base
 
     def sgd_step(theta_l, idx_l, cell_means, cell_w, own_base, counts_l, lr, key):
-        k_head, k_neg = jax.random.split(key)
-        rows, cl_local = sample_points(k_head, B_local, idx_l["cum_counts"], C)
-        pos_rows = idx_l["knn_idx"][rows]
-        pos_w = idx_l["knn_w"][rows]
-        th_i = theta_l[rows]
-        th_pos = theta_l[pos_rows]
-        neg_rows = sample_in_cluster(k_neg, cl_local, counts_l, C, S)
-        th_neg = theta_l[neg_rows]
-        own_cell = cl_local + own_base
-        p_own = counts_l.astype(jnp.float32)[cl_local] / n_total
-        neg_w = jnp.broadcast_to((float(Mn) * p_own / S)[:, None], (B_local, S))
+        with jax.named_scope(SCOPE_SAMPLE):
+            k_head, k_neg = jax.random.split(key)
+            rows, cl_local = sample_points(k_head, B_local, idx_l["cum_counts"], C)
+            pos_rows = idx_l["knn_idx"][rows]
+            pos_w = idx_l["knn_w"][rows]
+            neg_rows = sample_in_cluster(k_neg, cl_local, counts_l, C, S)
+            own_cell = cl_local + own_base
+            p_own = counts_l.astype(jnp.float32)[cl_local] / n_total
+            neg_w = jnp.broadcast_to((float(Mn) * p_own / S)[:, None], (B_local, S))
+        with jax.named_scope(SCOPE_GATHER):
+            th_i = theta_l[rows]
+            th_pos = theta_l[pos_rows]
+            th_neg = theta_l[neg_rows]
         cell_means = jax.lax.stop_gradient(cell_means)
 
         def loss_fn(ti, tp, tn):
@@ -141,14 +153,9 @@ def make_sharded_epoch_fn(
             )
             return jnp.mean(per_head)
 
-        loss, (g_i, g_pos, g_neg) = jax.value_and_grad(loss_fn, argnums=(0, 1, 2))(
-            th_i, th_pos, th_neg
+        return sgd_update(
+            theta_l, loss_fn, rows, pos_rows, neg_rows, th_i, th_pos, th_neg, lr
         )
-        d = theta_l.shape[1]
-        theta_l = theta_l.at[rows].add(-lr * g_i)
-        theta_l = theta_l.at[pos_rows.reshape(-1)].add(-lr * g_pos.reshape(-1, d))
-        theta_l = theta_l.at[neg_rows.reshape(-1)].add(-lr * g_neg.reshape(-1, d))
-        return theta_l, loss
 
     row_spec = P((pod_axis,) + tuple(shard_axes) if pod_axis else shard_axes)
     specs_in = (
@@ -173,10 +180,11 @@ def make_sharded_epoch_fn(
         check_vma=False,
     )
     def epoch(theta_l, idx_l, counts_global, lr0, lr1, key):
-        shard_idx, _ = shard_index_and_count(mesh, all_axes)
-        shard_off = shard_idx * Kl
-        if n_shards > 1:  # decorrelate shards; 1 shard matches the local stream
-            key = jax.random.fold_in(key, shard_idx)
+        with jax.named_scope(SCOPE_SAMPLE):
+            shard_idx, _ = shard_index_and_count(mesh, all_axes)
+            shard_off = shard_idx * Kl
+            if n_shards > 1:  # decorrelate shards; 1 shard matches the local stream
+                key = jax.random.fold_in(key, shard_idx)
         counts_l = idx_l["counts"]
 
         def chunk_body(carry, c):
@@ -187,7 +195,9 @@ def make_sharded_epoch_fn(
 
             def step_body(carry, t):
                 theta_l = carry
-                lr = lr0 + (lr1 - lr0) * (t / steps_per_epoch)
+                with jax.named_scope(SCOPE_SAMPLE):
+                    lr = lr0 + (lr1 - lr0) * (t / steps_per_epoch)
+                    step_key = jax.random.fold_in(key, t)
                 theta_l, loss = sgd_step(
                     theta_l,
                     idx_l,
@@ -196,19 +206,21 @@ def make_sharded_epoch_fn(
                     own_base,
                     counts_l,
                     lr,
-                    jax.random.fold_in(key, t),
+                    step_key,
                 )
                 return theta_l, loss
 
             theta_l, losses_ = jax.lax.scan(
                 step_body, theta_l, t0 + jnp.arange(refresh)
             )
-            return (theta_l, t0 + refresh), jnp.mean(losses_)
+            with jax.named_scope(SCOPE_LOSS):
+                return (theta_l, t0 + refresh), jnp.mean(losses_)
 
         (theta_l, _), chunk_losses = jax.lax.scan(
             chunk_body, (theta_l, jnp.zeros((), jnp.int32)), jnp.arange(n_chunks)
         )
-        loss = jax.lax.pmean(jnp.mean(chunk_losses), all_axes)
+        with jax.named_scope(SCOPE_LOSS):
+            loss = jax.lax.pmean(jnp.mean(chunk_losses), all_axes)
         return theta_l, loss
 
     return epoch

@@ -37,6 +37,20 @@ if TYPE_CHECKING:  # runtime import is lazy (repro.index imports repro.core)
 
 
 # ---------------------------------------------------------------------------
+# Stage names of the fit step
+# ---------------------------------------------------------------------------
+
+# ``jax.named_scope`` names that split every op of an epoch's step into the
+# fit's stages; a profiler trace carries them in each op's ``op_name``. They
+# are metadata only: the compiled program is the same op for op without them.
+SCOPE_SAMPLE = "nomad_sample"  # heads, negatives, kNN row lookups, step key and lr
+SCOPE_GATHER = "nomad_gather"  # θ rows of heads, positives and negatives
+SCOPE_LOSS = "nomad_loss"  # the loss and its gradient (the fused kernel)
+SCOPE_SCATTER = "nomad_scatter"  # the sparse SGD update of θ
+SCOPE_MEANS = "nomad_means"  # the per-cell means refresh (and its exchange)
+
+
+# ---------------------------------------------------------------------------
 # Sampling helpers (cluster-major layout)
 # ---------------------------------------------------------------------------
 
@@ -75,6 +89,21 @@ def local_means(theta_rows: jax.Array, counts: jax.Array, capacity: int):
 # ---------------------------------------------------------------------------
 
 
+def sgd_update(theta, loss_fn, rows, pos_rows, neg_rows, th_i, th_pos, th_neg, lr):
+    """The loss of a step and its sparse SGD update of θ: only the rows the
+    step touched move (reaction forces included). Returns (theta, loss)."""
+    with jax.named_scope(SCOPE_LOSS):
+        loss, (g_i, g_pos, g_neg) = jax.value_and_grad(loss_fn, argnums=(0, 1, 2))(
+            th_i, th_pos, th_neg
+        )
+    with jax.named_scope(SCOPE_SCATTER):
+        d = theta.shape[1]
+        theta = theta.at[rows].add(-lr * g_i)
+        theta = theta.at[pos_rows.reshape(-1)].add(-lr * g_pos.reshape(-1, d))
+        theta = theta.at[neg_rows.reshape(-1)].add(-lr * g_neg.reshape(-1, d))
+    return theta, loss
+
+
 def make_step_fn(
     cfg: NomadConfig,
     *,
@@ -100,26 +129,29 @@ def make_step_fn(
     C = cfg.cluster_capacity
 
     def step(theta, idx, means, global_counts, lr, key):
-        k_head, k_neg = jax.random.split(key)
-        rows, cl_local = sample_points(k_head, B, idx["cum_counts"], C)
-        pos_rows = idx["knn_idx"][rows]  # (B, k)
-        pos_w = idx["knn_w"][rows]  # (B, k)
-        th_i = theta[rows]
-        th_pos = theta[pos_rows]
+        with jax.named_scope(SCOPE_SAMPLE):
+            k_head, k_neg = jax.random.split(key)
+            rows, cl_local = sample_points(k_head, B, idx["cum_counts"], C)
+            pos_rows = idx["knn_idx"][rows]  # (B, k)
+            pos_w = idx["knn_w"][rows]  # (B, k)
+            if method == "infonc":
+                # Eq. 2 baseline: |M| noise tails uniform over the full support
+                neg_rows, _ = sample_points(k_neg, B * Mn, idx["cum_counts"], C)
+                neg_rows = neg_rows.reshape(B, Mn)
+            else:
+                neg_rows = sample_in_cluster(k_neg, cl_local, idx["counts"], C, S)
+                cell_global = cl_local + cluster_offset
+        with jax.named_scope(SCOPE_GATHER):
+            th_i = theta[rows]
+            th_pos = theta[pos_rows]
+            th_neg = theta[neg_rows]
 
         if method == "infonc":
-            # Eq. 2 baseline: |M| noise tails uniform over the full support
-            neg_rows, _ = sample_points(k_neg, B * Mn, idx["cum_counts"], C)
-            neg_rows = neg_rows.reshape(B, Mn)
-            th_neg = theta[neg_rows]
 
             def loss_fn(ti, tp, tn):
                 return losses.infonc_tsne_loss(ti, tp, pos_w, tn)
 
         else:
-            neg_rows = sample_in_cluster(k_neg, cl_local, idx["counts"], C, S)
-            th_neg = theta[neg_rows]
-            cell_global = cl_local + cluster_offset
 
             def loss_fn(ti, tp, tn):
                 return losses.nomad_loss(
@@ -135,14 +167,7 @@ def make_step_fn(
                     impl=cfg.resolved_kernel_impl(),
                 )
 
-        loss, (g_i, g_pos, g_neg) = jax.value_and_grad(loss_fn, argnums=(0, 1, 2))(
-            th_i, th_pos, th_neg
-        )
-        # sparse SGD: only touched rows are updated (reaction forces included)
-        theta = theta.at[rows].add(-lr * g_i)
-        theta = theta.at[pos_rows.reshape(-1)].add(-lr * g_pos.reshape(-1, theta.shape[1]))
-        theta = theta.at[neg_rows.reshape(-1)].add(-lr * g_neg.reshape(-1, theta.shape[1]))
-        return theta, loss
+        return sgd_update(theta, loss_fn, rows, pos_rows, neg_rows, th_i, th_pos, th_neg, lr)
 
     return step
 
@@ -169,29 +194,32 @@ def make_partial_step_fn(
     C = cfg.cluster_capacity
 
     def step(theta, idx, means, global_counts, lr, key):
-        k_head, k_neg = jax.random.split(key)
-        acum = idx["aff_cum_counts"]
-        u = jax.random.randint(k_head, (B,), 0, acum[-1])
-        a = jnp.searchsorted(acum, u, side="right").astype(jnp.int32)
-        start = jnp.where(a > 0, acum[a - 1], 0)
-        cell = idx["aff_cells"][a]  # global cell ids
-        rows = cell * C + (u - start)
-        pos_rows = idx["knn_idx"][rows]
-        pos_w = idx["knn_w"][rows]
-        th_i = theta[rows]
-        th_pos = theta[pos_rows]
+        with jax.named_scope(SCOPE_SAMPLE):
+            k_head, k_neg = jax.random.split(key)
+            acum = idx["aff_cum_counts"]
+            u = jax.random.randint(k_head, (B,), 0, acum[-1])
+            a = jnp.searchsorted(acum, u, side="right").astype(jnp.int32)
+            start = jnp.where(a > 0, acum[a - 1], 0)
+            cell = idx["aff_cells"][a]  # global cell ids
+            rows = cell * C + (u - start)
+            pos_rows = idx["knn_idx"][rows]
+            pos_w = idx["knn_w"][rows]
+            if method == "infonc":
+                neg_rows, _ = sample_points(k_neg, B * Mn, idx["cum_counts"], C)
+                neg_rows = neg_rows.reshape(B, Mn)
+            else:
+                neg_rows = sample_in_cluster(k_neg, cell, idx["counts"], C, S)
+        with jax.named_scope(SCOPE_GATHER):
+            th_i = theta[rows]
+            th_pos = theta[pos_rows]
+            th_neg = theta[neg_rows]
 
         if method == "infonc":
-            neg_rows, _ = sample_points(k_neg, B * Mn, idx["cum_counts"], C)
-            neg_rows = neg_rows.reshape(B, Mn)
-            th_neg = theta[neg_rows]
 
             def loss_fn(ti, tp, tn):
                 return losses.infonc_tsne_loss(ti, tp, pos_w, tn)
 
         else:
-            neg_rows = sample_in_cluster(k_neg, cell, idx["counts"], C, S)
-            th_neg = theta[neg_rows]
 
             def loss_fn(ti, tp, tn):
                 return losses.nomad_loss(
@@ -207,13 +235,7 @@ def make_partial_step_fn(
                     impl=cfg.resolved_kernel_impl(),
                 )
 
-        loss, (g_i, g_pos, g_neg) = jax.value_and_grad(loss_fn, argnums=(0, 1, 2))(
-            th_i, th_pos, th_neg
-        )
-        theta = theta.at[rows].add(-lr * g_i)
-        theta = theta.at[pos_rows.reshape(-1)].add(-lr * g_pos.reshape(-1, theta.shape[1]))
-        theta = theta.at[neg_rows.reshape(-1)].add(-lr * g_neg.reshape(-1, theta.shape[1]))
-        return theta, loss
+        return sgd_update(theta, loss_fn, rows, pos_rows, neg_rows, th_i, th_pos, th_neg, lr)
 
     return step
 
@@ -230,26 +252,31 @@ def make_epoch_fn(cfg: NomadConfig, step_fn, steps_per_epoch: int):
 
     @jax.jit
     def epoch(theta, idx, lr0, lr1, epoch_key):
-        counts_f = idx["counts"].astype(jnp.float32)
+        with jax.named_scope(SCOPE_MEANS):
+            counts_f = idx["counts"].astype(jnp.float32)
 
         def body(carry, t):
             theta, means = carry
-            means = jax.lax.cond(
-                t % refresh == 0,
-                lambda th: local_means(th, idx["counts"], C),
-                lambda th: means,
-                theta,
-            )
-            lr = lr0 + (lr1 - lr0) * (t / steps_per_epoch)
-            key = jax.random.fold_in(epoch_key, t)
+            with jax.named_scope(SCOPE_MEANS):
+                means = jax.lax.cond(
+                    t % refresh == 0,
+                    lambda th: local_means(th, idx["counts"], C),
+                    lambda th: means,
+                    theta,
+                )
+            with jax.named_scope(SCOPE_SAMPLE):
+                lr = lr0 + (lr1 - lr0) * (t / steps_per_epoch)
+                key = jax.random.fold_in(epoch_key, t)
             theta, loss = step_fn(theta, idx, means, counts_f, lr, key)
             return (theta, means), loss
 
-        means0 = local_means(theta, idx["counts"], C)
+        with jax.named_scope(SCOPE_MEANS):
+            means0 = local_means(theta, idx["counts"], C)
         (theta, _), losses_ = jax.lax.scan(
             body, (theta, means0), jnp.arange(steps_per_epoch)
         )
-        return theta, jnp.mean(losses_)
+        with jax.named_scope(SCOPE_LOSS):
+            return theta, jnp.mean(losses_)
 
     return epoch
 

@@ -205,6 +205,10 @@ def sync_processes(tag: str = "sync") -> None:
 # Strategies
 # ---------------------------------------------------------------------------
 
+# host spans around each epoch's dispatch (see ExecutionStrategy._dispatch)
+SPAN_DISPATCH = "nomad.fit.dispatch"
+SPAN_SYNC = "nomad.fit.sync"
+
 
 class ExecutionStrategy:
     """Where/how one NOMAD epoch runs. Stateful: ``prepare`` then ``run_epoch``."""
@@ -223,7 +227,18 @@ class ExecutionStrategy:
 
     def run_epoch(self, theta, epoch: int, lr0: float, lr1: float, key):
         """One epoch: ``(theta, lr schedule, rng) -> (theta, mean_loss)``."""
-        raise NotImplementedError
+        return self._dispatch(theta, self._idx, lr0, lr1, key)
+
+    def _dispatch(self, *args):
+        """Run the jitted epoch on ``args`` and wait for its loss, under the
+        host spans ``nomad.fit.dispatch`` (the enqueue) and
+        ``nomad.fit.sync`` (the wait): profiler events on the device trace's
+        clock, so an idle gap of the device falls in one or the other or
+        outside both."""
+        with jax.profiler.TraceAnnotation(SPAN_DISPATCH):
+            theta, loss = self._epoch_fn(*args)
+        with jax.profiler.TraceAnnotation(SPAN_SYNC):
+            return theta, float(loss)
 
     # -- introspection ---------------------------------------------------------
 
@@ -266,10 +281,6 @@ class LocalStrategy(ExecutionStrategy):
         step_fn = make_step_fn(cfg, method=method)
         self._epoch_fn = make_epoch_fn(cfg, step_fn, self._steps)
         return jnp.asarray(theta0)
-
-    def run_epoch(self, theta, epoch, lr0, lr1, key):
-        theta, loss = self._epoch_fn(theta, self._idx, lr0, lr1, key)
-        return theta, float(loss)
 
 
 class PartialRefineStrategy(ExecutionStrategy):
@@ -315,10 +326,6 @@ class PartialRefineStrategy(ExecutionStrategy):
         step_fn = make_partial_step_fn(cfg, method=method, n_total=index.n_points)
         self._epoch_fn = make_epoch_fn(cfg, step_fn, self._steps)
         return jnp.asarray(theta0)
-
-    def run_epoch(self, theta, epoch, lr0, lr1, key):
-        theta, loss = self._epoch_fn(theta, self._idx, lr0, lr1, key)
-        return theta, float(loss)
 
 
 class ShardedStrategy(ExecutionStrategy):
@@ -426,10 +433,7 @@ class ShardedStrategy(ExecutionStrategy):
         return jax.device_put(jnp.asarray(theta0), row_sh)
 
     def run_epoch(self, theta, epoch, lr0, lr1, key):
-        theta, loss = self._epoch_fn(
-            theta, self._idx, self._counts_global, lr0, lr1, key
-        )
-        return theta, float(loss)
+        return self._dispatch(theta, self._idx, self._counts_global, lr0, lr1, key)
 
 
 class HierarchicalStrategy(ShardedStrategy):

@@ -574,13 +574,13 @@ class IndexBuilder:
 
         @contextmanager
         def stage(label):
-            t0 = time.time()
+            t0 = time.perf_counter()
             yield
             # accumulate: the straggler force-place re-enters "assign"
-            stage_s[label] = stage_s.get(label, 0.0) + (time.time() - t0)
+            stage_s[label] = stage_s.get(label, 0.0) + (time.perf_counter() - t0)
             stage_rss[label] = _rss_mb()
 
-        t0 = time.time()
+        t0 = time.perf_counter()
         if name == "streamed":
             index, stragglers = self._build_streamed(as_store(x), stage)
             n_shards = 1
@@ -596,7 +596,7 @@ class IndexBuilder:
         self.report = BuildReport(
             strategy=name,
             n_shards=n_shards,
-            total_s=time.time() - t0,
+            total_s=time.perf_counter() - t0,
             stage_s=stage_s,
             stage_rss_mb=stage_rss,
             stragglers=stragglers,

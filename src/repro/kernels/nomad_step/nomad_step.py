@@ -174,6 +174,7 @@ def nomad_step_fwd_pallas(
             jax.ShapeDtypeStruct((1, B), jnp.float32),
         ],
         interpret=interpret,
+        name="nomad_step_fwd",
     )(th, pos, pw, neg, nw, mu, cw, own)
 
 
@@ -213,4 +214,5 @@ def nomad_step_bwd_pallas(
             jax.ShapeDtypeStruct((s * d, B), jnp.float32),
         ],
         interpret=interpret,
+        name="nomad_step_bwd",
     )(th, pos, pw, neg, nw, mu, cw, own, m, gbar)
