@@ -154,7 +154,8 @@ def make_sharded_epoch_fn(
             return jnp.mean(per_head)
 
         return sgd_update(
-            theta_l, loss_fn, rows, pos_rows, neg_rows, th_i, th_pos, th_neg, lr
+            theta_l, loss_fn, rows, pos_rows, neg_rows, th_i, th_pos, th_neg, lr,
+            cfg.resolved_kernel_impl(),
         )
 
     row_spec = P((pod_axis,) + tuple(shard_axes) if pod_axis else shard_axes)

@@ -31,6 +31,7 @@ from typing import TYPE_CHECKING
 from repro.configs.base import NomadConfig
 from repro.core import losses
 from repro.core.pca import pca_init
+from repro.kernels import registry
 
 if TYPE_CHECKING:  # runtime import is lazy (repro.index imports repro.core)
     from repro.index.ann import AnnIndex
@@ -89,18 +90,21 @@ def local_means(theta_rows: jax.Array, counts: jax.Array, capacity: int):
 # ---------------------------------------------------------------------------
 
 
-def sgd_update(theta, loss_fn, rows, pos_rows, neg_rows, th_i, th_pos, th_neg, lr):
+def sgd_update(theta, loss_fn, rows, pos_rows, neg_rows, th_i, th_pos, th_neg, lr, impl):
     """The loss of a step and its sparse SGD update of θ: only the rows the
-    step touched move (reaction forces included). Returns (theta, loss)."""
+    step touched move (reaction forces included). The update of the heads,
+    positives and negatives is one ``"row_add"`` registry kernel: on a TPU
+    the pairs sorted by row and added in one sweep over θ, in place of a
+    scatter-add that serialises on repeated rows. Returns (theta, loss)."""
     with jax.named_scope(SCOPE_LOSS):
         loss, (g_i, g_pos, g_neg) = jax.value_and_grad(loss_fn, argnums=(0, 1, 2))(
             th_i, th_pos, th_neg
         )
     with jax.named_scope(SCOPE_SCATTER):
         d = theta.shape[1]
-        theta = theta.at[rows].add(-lr * g_i)
-        theta = theta.at[pos_rows.reshape(-1)].add(-lr * g_pos.reshape(-1, d))
-        theta = theta.at[neg_rows.reshape(-1)].add(-lr * g_neg.reshape(-1, d))
+        all_rows = jnp.concatenate([rows, pos_rows.reshape(-1), neg_rows.reshape(-1)])
+        grads = jnp.concatenate([g_i, g_pos.reshape(-1, d), g_neg.reshape(-1, d)])
+        theta = registry.dispatch("row_add", theta, all_rows, -lr * grads, impl=impl)
     return theta, loss
 
 
@@ -167,7 +171,10 @@ def make_step_fn(
                     impl=cfg.resolved_kernel_impl(),
                 )
 
-        return sgd_update(theta, loss_fn, rows, pos_rows, neg_rows, th_i, th_pos, th_neg, lr)
+        return sgd_update(
+            theta, loss_fn, rows, pos_rows, neg_rows, th_i, th_pos, th_neg, lr,
+            cfg.resolved_kernel_impl(),
+        )
 
     return step
 
@@ -235,7 +242,10 @@ def make_partial_step_fn(
                     impl=cfg.resolved_kernel_impl(),
                 )
 
-        return sgd_update(theta, loss_fn, rows, pos_rows, neg_rows, th_i, th_pos, th_neg, lr)
+        return sgd_update(
+            theta, loss_fn, rows, pos_rows, neg_rows, th_i, th_pos, th_neg, lr,
+            cfg.resolved_kernel_impl(),
+        )
 
     return step
 

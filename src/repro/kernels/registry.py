@@ -105,6 +105,7 @@ def _load_builtins() -> None:
     import repro.kernels.kmeans_assign.ops  # noqa: F401
     import repro.kernels.nomad_step.ops  # noqa: F401
     import repro.kernels.pairwise.ops  # noqa: F401
+    import repro.kernels.row_add.ops  # noqa: F401
 
 
 def get(name: str) -> KernelSpec:

@@ -18,6 +18,7 @@ worker loads it.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -121,3 +122,58 @@ def test_pairwise_compiles_at_cluster_width(one_chip):
     _spec, kernel = _kernel("pairwise")
     shapes = _shapes(_sig(5120, 5120, 768), one_chip)
     assert "tpu_custom_call" in _compiled_text(kernel, shapes)
+
+
+def _computation(hlo: str, name: str) -> str:
+    """The text of the computation ``name`` of an HLO module."""
+    start = hlo.index(f"\n%{name} (") + 1
+    return hlo[start : hlo.index("\n}\n", start)]
+
+
+def test_fit_epoch_at_wiki60m_theta_updates_theta_in_place(one_chip, monkeypatch):
+    """The fit epoch compiles at wiki60m's θ (K = 8,192 cells of C = 9,155
+    rows). Its scan body writes θ once a step, by the ``row_add`` kernel,
+    which takes θ transposed as a bitcast: no copy of the whole θ (a stray
+    one costs ~1.5 ms a step), and no scatter of θ."""
+    from repro.configs.base import NomadConfig
+    from repro.core import nomad
+
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    cfg = NomadConfig(
+        n_points=60_000_000, dim=1024, n_clusters=8192, n_neighbors=15, n_noise=128,
+        n_exact_negatives=16, batch_size=8192, n_epochs=80, steps_per_epoch=2,
+        kernel_impl="pallas",
+    )
+    K, k = cfg.n_clusters, cfg.n_neighbors
+    R = K * cfg.cluster_capacity
+    assert R == 74_997_760
+
+    def shape(s, dtype):
+        return jax.ShapeDtypeStruct(s, dtype, sharding=one_chip)
+
+    idx = {
+        "knn_idx": shape((R, k), jnp.int32),
+        "knn_w": shape((R, k), jnp.float32),
+        "counts": shape((K,), jnp.int32),
+        "cum_counts": shape((K,), jnp.int32),
+    }
+    epoch = nomad.make_epoch_fn(cfg, nomad.make_step_fn(cfg), cfg.steps_per_epoch)
+    scalar = shape((), jnp.float32)
+    hlo = epoch.lower(
+        shape((R, 2), jnp.float32), idx, scalar, scalar, jax.random.key(0)
+    ).compile().as_text()
+
+    theta = re.escape(f"f32[{R},2]")
+    scan = re.findall(rf"\n[^\n]*{theta}[^\n]* while\([^\n]*body=%([\w.\-]+)", hlo)
+    assert len(scan) == 1, scan
+    body = _computation(hlo, scan[0])
+    whole = rf"(?:{theta}|{re.escape(f'f32[2,{R}]')})"
+    writes = re.findall(
+        rf"\n\s*(?:ROOT )?%\S+ = {whole}\{{[^}}]*\}} ([\w\-]+)\(([^\n]*)", body
+    )
+    ops = sorted(op for op, _ in writes if op != "get-tuple-element")
+    assert ops == ["bitcast", "bitcast", "custom-call"], writes
+    (call,) = [rest for op, rest in writes if op == "custom-call"]
+    assert 'custom_call_target="tpu_custom_call"' in call and "row_add" in call
+    assert nomad.SCOPE_SCATTER in call
+    assert " scatter(" not in body
