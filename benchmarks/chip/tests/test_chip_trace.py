@@ -9,7 +9,9 @@ from conftest import HERE
 from lib import trace
 from lib.trace import Op
 
-RECORDED = sorted(glob.glob(os.path.join(HERE, "data", "**", "*.xplane.pb"), recursive=True))
+# the fit windows recorded on the chip (``data/build_tiny`` is the build's,
+# read in test_chip_build_check.py)
+RECORDED = sorted(glob.glob(os.path.join(HERE, "data", "fit_*", "**", "*.xplane.pb"), recursive=True))
 
 WHILE = "%while.3 = (s32[], f32[800,2]{0,1:T(2,128)}) while((s32[], f32[800,2]) %tuple.1), condition=%c, body=%b"
 GATHER = "%fusion.7 = f32[96,2]{0,1:T(2,128)} fusion(f32[800,2]{0,1:T(2,128)} %p, s32[96]{0} %i), kind=kCustom"
