@@ -40,7 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import NomadConfig
 from repro.core import losses
@@ -54,6 +54,11 @@ from repro.core.nomad import (
     sample_points,
     sgd_update,
 )
+
+# ``jax.named_scope`` of the shards' collectives: the means' all-gather
+# (inside ``nomad_means``) and the loss's ``pmean`` (inside ``nomad_loss``),
+# so a profiler trace tells the exchange from the stage's own compute.
+SCOPE_EXCHANGE = "nomad_exchange"
 
 
 def shard_index_and_count(mesh: Mesh, axes) -> tuple:
@@ -79,7 +84,8 @@ def make_sharded_epoch_fn(
 
     ``theta``: (K·C, d) global view, rows sharded over ``shard_axes``
     (+ ``pod_axis`` outermost if given). ``idx`` dict likewise row-sharded
-    except the replicated ``counts_global``.
+    except the replicated ``counts_global`` (:func:`place_index`); its
+    ``knn_idx`` holds global row ids, made shard-local per step.
     """
     C = cfg.cluster_capacity
     K = cfg.n_clusters
@@ -103,11 +109,13 @@ def make_sharded_epoch_fn(
         """
         means_l = local_means(theta_l, counts_l, C)  # (Kl, d)
         if not hierarchical:
-            means_g = jax.lax.all_gather(means_l, all_axes, axis=0, tiled=True)
+            with jax.named_scope(SCOPE_EXCHANGE):
+                means_g = jax.lax.all_gather(means_l, all_axes, axis=0, tiled=True)
             cell_w = float(Mn) * counts_global.astype(jnp.float32) / n_total
             return means_g, cell_w, shard_off
         # ---- hierarchical: full means intra-pod, super-means inter-pod ----
-        means_pod = jax.lax.all_gather(means_l, tuple(shard_axes), axis=0, tiled=True)
+        with jax.named_scope(SCOPE_EXCHANGE):
+            means_pod = jax.lax.all_gather(means_l, tuple(shard_axes), axis=0, tiled=True)
         n_pods = mesh.shape[pod_axis]
         Kp = K // n_pods  # clusters per pod
         pod_idx = jax.lax.axis_index(pod_axis)
@@ -116,8 +124,9 @@ def make_sharded_epoch_fn(
         )
         w_sum = jnp.maximum(jnp.sum(pod_counts), 1.0)
         super_mean = jnp.sum(means_pod * pod_counts[:, None], 0, keepdims=True) / w_sum
-        super_means = jax.lax.all_gather(super_mean[0], pod_axis, axis=0, tiled=False)
-        super_counts = jax.lax.all_gather(jnp.sum(pod_counts), pod_axis, tiled=False)
+        with jax.named_scope(SCOPE_EXCHANGE):
+            super_means = jax.lax.all_gather(super_mean[0], pod_axis, axis=0, tiled=False)
+            super_counts = jax.lax.all_gather(jnp.sum(pod_counts), pod_axis, tiled=False)
         # own pod's super-mean is excluded (its cells are already exact/full)
         own_pod = jax.lax.axis_index(pod_axis)
         super_w = float(Mn) * super_counts / n_total
@@ -128,11 +137,11 @@ def make_sharded_epoch_fn(
         own_base = shard_off - pod_idx * Kp  # own cells indexed within the pod block
         return cell_means, cell_w, own_base
 
-    def sgd_step(theta_l, idx_l, cell_means, cell_w, own_base, counts_l, lr, key):
+    def sgd_step(theta_l, idx_l, cell_means, cell_w, own_base, row_off, counts_l, lr, key):
         with jax.named_scope(SCOPE_SAMPLE):
             k_head, k_neg = jax.random.split(key)
             rows, cl_local = sample_points(k_head, B_local, idx_l["cum_counts"], C)
-            pos_rows = idx_l["knn_idx"][rows]
+            pos_rows = idx_l["knn_idx"][rows] - row_off  # global → shard-local rows
             pos_w = idx_l["knn_w"][rows]
             neg_rows = sample_in_cluster(k_neg, cl_local, counts_l, C, S)
             own_cell = cl_local + own_base
@@ -184,6 +193,7 @@ def make_sharded_epoch_fn(
         with jax.named_scope(SCOPE_SAMPLE):
             shard_idx, _ = shard_index_and_count(mesh, all_axes)
             shard_off = shard_idx * Kl
+            row_off = shard_off * C
             if n_shards > 1:  # decorrelate shards; 1 shard matches the local stream
                 key = jax.random.fold_in(key, shard_idx)
         counts_l = idx_l["counts"]
@@ -205,6 +215,7 @@ def make_sharded_epoch_fn(
                     cell_means,
                     cell_w,
                     own_base,
+                    row_off,
                     counts_l,
                     lr,
                     step_key,
@@ -221,7 +232,9 @@ def make_sharded_epoch_fn(
             chunk_body, (theta_l, jnp.zeros((), jnp.int32)), jnp.arange(n_chunks)
         )
         with jax.named_scope(SCOPE_LOSS):
-            loss = jax.lax.pmean(jnp.mean(chunk_losses), all_axes)
+            loss = jnp.mean(chunk_losses)
+            with jax.named_scope(SCOPE_EXCHANGE):
+                loss = jax.lax.pmean(loss, all_axes)
         return theta_l, loss
 
     return epoch
@@ -232,34 +245,65 @@ def make_sharded_epoch_fn(
 # ---------------------------------------------------------------------------
 
 
-def shard_index_arrays(index, n_shards: int):
-    """Split an AnnIndex into the global-view arrays the epoch fn expects.
+def place_rows(a, sharding: NamedSharding, dtype=None) -> jax.Array:
+    """``a`` on ``sharding``, each device given only its own block.
 
-    kNN row ids are rebased to be shard-local (subtracting the shard's row
-    offset) — positives never cross shards by construction, this just
-    asserts it numerically.
+    A host array is cut block by block (``jax.make_array_from_callback``),
+    so no device receives more than its shard; a ``jax.Array``, sharded or
+    not, is re-placed on the devices (a no-op where it already lies there).
+    ``dtype`` defaults to JAX's canonical form of ``a``'s.
     """
-    K, C = index.n_clusters, index.capacity
+    dtype = jnp.dtype(jax.dtypes.canonicalize_dtype(a.dtype) if dtype is None else dtype)
+    if isinstance(a, jax.Array):
+        return jax.device_put(a if a.dtype == dtype else a.astype(dtype), sharding)
+    return jax.make_array_from_callback(
+        a.shape, sharding, lambda blk: np.asarray(a[blk], dtype)
+    )
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _edges_cross(knn_idx, rows_per: int):
+    """Whether any kNN row id lies outside its own row's shard."""
+    row = jax.lax.broadcasted_iota(jnp.int32, knn_idx.shape, 0)
+    lo = row - row % rows_per
+    return jnp.any((knn_idx < lo) | (knn_idx >= lo + rows_per))
+
+
+def place_index(index, mesh: Mesh, axes) -> tuple:
+    """Place an index's arrays on ``mesh``, rows split over ``axes``.
+
+    Returns ``(idx, counts_global)``: the row-sharded ``knn_idx`` (global
+    row ids; the epoch makes them shard-local), ``knn_w``, ``counts`` and
+    each shard's own ``cum_counts``, and the replicated float32 counts.
+    ``index`` needs ``knn_idx``, ``knn_w`` and ``counts``, as host arrays or
+    ``jax.Array``s; each device receives only its shard, so neither the
+    host nor any one device holds a copy of the whole graph. Positives
+    never cross shards by construction (a shard owns whole cells); one
+    device reduction asserts it.
+    """
+    n_shards = int(np.prod([mesh.shape[a] for a in axes]))
+    K = index.counts.shape[0]
     if K % n_shards:
         raise ValueError(f"n_clusters={K} not divisible by n_shards={n_shards}")
-    Kl = K // n_shards
-    rows_per = Kl * C
-    knn_local = index.knn_idx.copy()
-    for s in range(n_shards):
-        lo, hi = s * rows_per, (s + 1) * rows_per
-        blk = knn_local[lo:hi]
-        if blk.size and ((blk < lo) | (blk >= hi)).any():
-            raise AssertionError("kNN edge crosses shard boundary")
-        knn_local[lo:hi] = blk - lo
-    cum = np.concatenate(
-        [np.cumsum(index.counts[s * Kl : (s + 1) * Kl]) for s in range(n_shards)]
-    )
-    return {
-        "knn_idx": jnp.asarray(knn_local, jnp.int32),
-        "knn_w": jnp.asarray(index.knn_w, jnp.float32),
-        "counts": jnp.asarray(index.counts, jnp.int32),
-        "cum_counts": jnp.asarray(cum, jnp.int32),
+    row_sh = NamedSharding(mesh, P(axes, None))
+    vec_sh = NamedSharding(mesh, P(axes))
+    knn_idx = place_rows(index.knn_idx, row_sh, jnp.int32)
+    if _edges_cross(knn_idx, knn_idx.shape[0] // n_shards):
+        raise AssertionError("kNN edge crosses shard boundary")
+    counts = place_rows(index.counts, vec_sh, jnp.int32)
+    idx = {
+        "knn_idx": knn_idx,
+        "knn_w": place_rows(index.knn_w, row_sh, jnp.float32),
+        "counts": counts,
+        "cum_counts": jax.jit(
+            lambda c: jnp.cumsum(c.reshape(n_shards, -1), axis=1).reshape(-1),
+            out_shardings=vec_sh,
+        )(counts),
     }
+    counts_global = jax.jit(
+        lambda c: c.astype(jnp.float32), out_shardings=NamedSharding(mesh, P())
+    )(counts)
+    return idx, counts_global
 
 
 def fit_distributed(

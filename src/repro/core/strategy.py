@@ -208,6 +208,8 @@ def sync_processes(tag: str = "sync") -> None:
 # host spans around each epoch's dispatch (see ExecutionStrategy._dispatch)
 SPAN_DISPATCH = "nomad.fit.dispatch"
 SPAN_SYNC = "nomad.fit.sync"
+# host span around the sharded strategy's placement of θ and the index
+SPAN_PLACE = "nomad.fit.place"
 
 
 class ExecutionStrategy:
@@ -388,7 +390,7 @@ class ShardedStrategy(ExecutionStrategy):
         self.n_shards = n_shards
 
     def prepare(self, cfg, method, index, theta0):
-        from repro.core.distributed import make_sharded_epoch_fn, shard_index_arrays
+        from repro.core.distributed import make_sharded_epoch_fn, place_index, place_rows
 
         if method != "nomad":
             raise ValueError(
@@ -410,16 +412,9 @@ class ShardedStrategy(ExecutionStrategy):
         self._refresh = cfg.mean_refresh_steps or self._steps
 
         axes = ((self.pod_axis,) if self.pod_axis else ()) + self.shard_axes
-        row_sh = NamedSharding(self.mesh, P(axes, None))
-        vec_sh = NamedSharding(self.mesh, P(axes))
-        idx = shard_index_arrays(index, self.n_shards)
-        self._idx = {
-            "knn_idx": jax.device_put(idx["knn_idx"], row_sh),
-            "knn_w": jax.device_put(idx["knn_w"], row_sh),
-            "counts": jax.device_put(idx["counts"], vec_sh),
-            "cum_counts": jax.device_put(idx["cum_counts"], vec_sh),
-        }
-        self._counts_global = jnp.asarray(index.counts, jnp.float32)
+        with jax.profiler.TraceAnnotation(SPAN_PLACE):
+            self._idx, self._counts_global = place_index(index, self.mesh, axes)
+            theta = place_rows(theta0, NamedSharding(self.mesh, P(axes, None)))
         self._epoch_fn = jax.jit(
             make_sharded_epoch_fn(
                 cfg,
@@ -430,7 +425,7 @@ class ShardedStrategy(ExecutionStrategy):
                 n_shards=self.n_shards,
             )
         )
-        return jax.device_put(jnp.asarray(theta0), row_sh)
+        return theta
 
     def run_epoch(self, theta, epoch, lr0, lr1, key):
         return self._dispatch(theta, self._idx, self._counts_global, lr0, lr1, key)

@@ -207,7 +207,7 @@ def test_stage_scopes_are_single_path_components():
 
 
 def _sharded_report() -> str:
-    from repro.core.distributed import make_sharded_epoch_fn, shard_index_arrays
+    from repro.core.distributed import SCOPE_EXCHANGE, make_sharded_epoch_fn, place_index
     from repro.launch.mesh import make_mesh
 
     cfg = tiny_cfg(n_clusters=K)
@@ -220,7 +220,7 @@ def _sharded_report() -> str:
         knn_w = np.full((K * C, cfg.n_neighbors), 1.0 / cfg.n_neighbors, np.float32)
         counts = np.full((K,), N_PER_CELL, np.int32)
 
-    idx = shard_index_arrays(Index, 4)
+    idx, counts = place_index(Index, mesh, ("data",))
 
     def build():
         epoch = jax.jit(
@@ -229,7 +229,6 @@ def _sharded_report() -> str:
             )
         )
         theta = jnp.zeros((K * C, cfg.out_dim), jnp.float32)
-        counts = jnp.asarray(Index.counts, jnp.float32)
         return epoch.lower(theta, idx, counts, 0.1, 0.05, jax.random.key(0)).compile().as_text()
 
     scoped, plain = build(), unscoped(build)
@@ -238,6 +237,11 @@ def _sharded_report() -> str:
         "body_ops": len(body_ops(scoped)),
         "unscoped": unscoped_in_body(scoped),
         "all_gather": any(i[1].startswith("all-gather") for i in instructions(scoped)),
+        "collectives": sorted(
+            (i[1], SCOPE_EXCHANGE in i[2].split("/"))
+            for i in instructions(scoped)
+            if i[1].startswith(("all-gather", "all-reduce"))
+        ),
         "same": lines(scoped) == lines(plain),
     })
 
@@ -265,6 +269,14 @@ def test_sharded_epoch_ops_carry_one_scope(sharded):
 
 def test_sharded_epoch_scopes_are_metadata_only(sharded):
     assert sharded["same"]
+
+
+def test_sharded_exchange_ops_carry_the_exchange_scope(sharded):
+    """The means' all-gather and the loss's all-reduce are named
+    ``nomad_exchange`` (nested in ``nomad_means`` and ``nomad_loss``)."""
+    ops = [tuple(c) for c in sharded["collectives"]]
+    assert {op for op, _ in ops} >= {"all-gather", "all-reduce"}, ops
+    assert all(scoped for _, scoped in ops), ops
 
 
 # ---- host spans ---------------------------------------------------------------
