@@ -9,6 +9,10 @@ j** — i.e. how close i looks from j's perspective. Index 0 is j itself, so
 ranks of other points start at 1. The normaliser Z has k+1 terms exactly as
 written in Eq. 6 (it includes the r = k+1 term); we keep it verbatim for
 faithfulness — it is a constant, so it only scales the loss.
+
+Only ranks 1..k carry weight, so each tail's list is cut to its k+1 nearest
+(ties to the lower index, as a stable sort orders them): a rank is read by
+finding the head in its tail's list, and a head absent from it ranks above k.
 """
 
 from __future__ import annotations
@@ -22,19 +26,6 @@ def normalizer(k: int) -> float:
     return float(np.exp(1.0 / np.arange(1, k + 2)).sum())
 
 
-def rank_matrix(dist2: jnp.ndarray) -> jnp.ndarray:
-    """R[i, j] = rank of i in j's ascending-distance order (0 = j itself).
-
-    dist2: (C, C) squared distances with dist2[j, j] = 0.
-    """
-    # rank along each column: double argsort
-    order = jnp.argsort(dist2, axis=0)  # (C, C): order[r, j] = point at rank r w.r.t. j
-    C = dist2.shape[0]
-    ranks = jnp.zeros((C, C), jnp.int32)
-    ranks = ranks.at[order, jnp.arange(C)[None, :]].set(jnp.arange(C, dtype=jnp.int32)[:, None])
-    return ranks
-
-
 def edge_weights(dist2: jnp.ndarray, knn_idx: jnp.ndarray, k: int, valid: jnp.ndarray) -> jnp.ndarray:
     """Weights p(j|i) for each kNN edge i→j (Eq. 6).
 
@@ -43,10 +34,12 @@ def edge_weights(dist2: jnp.ndarray, knn_idx: jnp.ndarray, k: int, valid: jnp.nd
     valid:   (C,) real-point mask
     Returns (C, k) fp32 weights; invalid edges get 0.
     """
-    R = rank_matrix(dist2)
     C = dist2.shape[0]
-    rows = jnp.arange(C)[:, None]
-    r_ji = R[rows, knn_idx]  # rank of i from j's perspective → R[i, j]
+    m = min(k + 1, C)
+    # col_top[j, p] = the point at rank p from j (column j of dist2, ascending)
+    _, col_top = jax.lax.top_k(-dist2.T, m)
+    hit = col_top[knn_idx] == jnp.arange(C)[:, None, None]  # (C, k, m)
+    r_ji = jnp.min(jnp.where(hit, jnp.arange(m), k + 1), axis=-1)  # rank of i from j
     w = jnp.exp(1.0 / jnp.maximum(r_ji.astype(jnp.float32), 1.0)) / normalizer(k)
     w = jnp.where((r_ji >= 1) & (r_ji <= k), w, 0.0)
     w = jnp.where(valid[:, None] & valid[knn_idx], w, 0.0)

@@ -5,8 +5,9 @@ block, every cluster is a connected component of the ANN graph — the paper's
 device-locality property for positive forces.
 
 The pairwise-distance matrix dispatches through the kernel registry
-(kernel ``"pairwise"``, MXU form ‖x‖²+‖y‖²−2x·yᵀ). Top-k and the rank
-matrix stay in jnp (sort-heavy, VPU-bound either way).
+(kernel ``"pairwise"``, MXU form ‖x‖²+‖y‖²−2x·yᵀ). The two top-ks, each
+row's k nearest and each tail's k+1 nearest for the weights' ranks, stay in
+jnp (sort-heavy, VPU-bound either way).
 """
 
 from __future__ import annotations
@@ -21,7 +22,7 @@ from repro.core.rank_model import edge_weights
 BIG = jnp.float32(1e30)
 
 # bytes of (C, C) f32 distance tiles one batch of clusters may hold at once;
-# each cluster's kNN keeps several such tiles (distances, masks, ranks)
+# each cluster's kNN keeps several such tiles (distances, masks, transpose)
 _KNN_TILE_BYTES = 256 << 20
 
 

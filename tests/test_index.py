@@ -11,7 +11,7 @@ from repro.configs.base import NomadConfig
 from repro.data.synthetic import gaussian_mixture
 from repro.index.ann import build_index, _np_dist2
 from repro.index.kmeans import assign_jnp, capacity_assign, kmeans_fit, lsh_init_centroids
-from repro.index.knn import cluster_knn
+from repro.index.knn import _cluster_knn_jit, cluster_knn
 
 
 def test_kmeans_objective_nonincreasing():
@@ -84,6 +84,18 @@ def test_cluster_knn_respects_padding():
     # padded heads carry no edges; no edge points at a padded tail
     assert (w[real:] == 0).all()
     assert (knn[:real][w[:real] > 0] < real).all()
+
+
+def test_cluster_knn_has_no_sort_or_scatter():
+    """The in-cell kNN takes its neighbours and its weights' ranks from two
+    top-ks; a sort or scatter in the program means a (C, C) rank pass."""
+    C, D, k = 24, 4, 5
+    text = _cluster_knn_jit.lower(
+        jnp.zeros((C, D), jnp.float32), jnp.ones((C,), bool), k=k, impl="jnp"
+    ).as_text()
+    assert text.count("chlo.top_k") == 2
+    assert "stablehlo.sort" not in text
+    assert "stablehlo.scatter" not in text
 
 
 def test_build_index_layout_and_component_property():

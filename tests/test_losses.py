@@ -17,7 +17,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import losses
 from repro.core.cauchy import cauchy, cauchy_pairwise
-from repro.core.rank_model import edge_weights, normalizer, rank_matrix
+from repro.core.rank_model import edge_weights, normalizer
 
 
 # ---------------------------------------------------------------------------
@@ -47,10 +47,32 @@ def test_cauchy_range_symmetry_identity(seed, d):
 # ---------------------------------------------------------------------------
 
 
+def rank_matrix(dist2):
+    """Oracle: R[i, j] = rank of i in j's ascending-distance order (0 = j
+    itself), from a stable argsort of every column of ``dist2``."""
+    order = jnp.argsort(dist2, axis=0)  # (C, C): order[r, j] = point at rank r w.r.t. j
+    C = dist2.shape[0]
+    ranks = jnp.zeros((C, C), jnp.int32)
+    return ranks.at[order, jnp.arange(C)[None, :]].set(jnp.arange(C, dtype=jnp.int32)[:, None])
+
+
+def oracle_edge_weights(dist2, knn_idx, k, valid):
+    """Eq. 6 weights with every rank read from the full :func:`rank_matrix`."""
+    R = rank_matrix(dist2)
+    r_ji = R[jnp.arange(dist2.shape[0])[:, None], knn_idx]
+    w = jnp.exp(1.0 / jnp.maximum(r_ji.astype(jnp.float32), 1.0)) / normalizer(k)
+    w = jnp.where((r_ji >= 1) & (r_ji <= k), w, 0.0)
+    return jnp.where(valid[:, None] & valid[knn_idx], w, 0.0)
+
+
 def test_rank_matrix_definition():
     x = jnp.asarray([[0.0], [1.0], [3.0], [3.5]])
     d2 = jnp.square(x - x.T)
-    R = np.asarray(rank_matrix(d2))
+    # every row lists every tail (k = C), so w[i, j] is e^{1/R[i, j]}/Z, or 0 at rank 0
+    k = 4
+    w = np.asarray(edge_weights(d2, jnp.tile(jnp.arange(k), (k, 1)), k, jnp.ones((k,), bool)))
+    R = np.where(w > 0, np.rint(1.0 / np.log(np.where(w > 0, w, 1.0) * normalizer(k))), 0)
+    np.testing.assert_array_equal(R, np.asarray(rank_matrix(d2)))
     # rank of i w.r.t. column j; diagonal is 0 (j itself)
     assert (np.diag(R) == 0).all()
     # w.r.t. point 0 (x=0): order is [0, 1, 3, 3.5] → ranks 0,1,2,3
@@ -76,6 +98,53 @@ def test_edge_weights_properties(seed, k, c):
     R = np.asarray(rank_matrix(d2))
     r_ji = np.take_along_axis(R, np.asarray(knn), axis=1)
     assert ((w > 0) == ((r_ji >= 1) & (r_ji <= k))).all()
+    np.testing.assert_array_equal(w, np.asarray(oracle_edge_weights(d2, knn, k, valid)))
+
+
+def _cell_knn_inputs(x, k, real):
+    """(dist2, knn_idx, valid) as ``index/knn.py:_cluster_knn_jit`` hands
+    them to ``edge_weights``: padding at BIG, self left out of the search."""
+    C = x.shape[0]
+    big = jnp.float32(1e30)
+    d2 = jnp.sum(jnp.square(x[:, None] - x[None, :]), -1)
+    valid = jnp.arange(C) < real
+    pad = ~(valid[:, None] & valid[None, :])
+    _, knn = jax.lax.top_k(-(d2 + pad * big + jnp.eye(C) * big), k)
+    return d2 + pad * big, knn, valid
+
+
+@pytest.mark.parametrize(
+    "case", ["duplicate_rows", "integer_coords", "padded", "c_is_k_plus_1", "c_is_k", "vmap"]
+)
+def test_edge_weights_equal_rank_matrix_oracle(case):
+    """The k+1-nearest lookup gives the full rank matrix's weights bit for
+    bit, ties (lowest index first) included."""
+    rng = np.random.default_rng(17)
+    C, k, real = 48, 7, 48
+    x = rng.normal(0, 1, (C, 3))
+    if case == "duplicate_rows":  # pairs at distance 0
+        x[C // 2 :] = x[: C // 2]
+    elif case == "integer_coords":  # many equal distances
+        x = np.rint(2 * x)
+    elif case == "padded":
+        real = 31
+    elif case == "c_is_k_plus_1":
+        C, x = k + 1, x[: k + 1]
+    elif case == "c_is_k":
+        C, x = k, x[:k]
+    if case == "vmap":  # as _knn_over_clusters maps the cells
+        xs = jnp.asarray(rng.normal(0, 1, (3, C, 3)).round(1), jnp.float32)
+        args = jax.vmap(lambda xb, r: _cell_knn_inputs(xb, k, r))(xs, jnp.asarray([C, 29, 40]))
+        got = jax.vmap(lambda d, n, v: edge_weights(d, n, k, v))(*args)
+        want = jax.vmap(lambda d, n, v: oracle_edge_weights(d, n, k, v))(*args)
+    else:
+        kk = min(k, C)  # a cell of k slots searches k of them (self at BIG)
+        d2, knn, valid = _cell_knn_inputs(jnp.asarray(x, jnp.float32), kk, real)
+        got = edge_weights(d2, knn, kk, valid)
+        want = oracle_edge_weights(d2, knn, kk, valid)
+    got, want = np.asarray(got), np.asarray(want)
+    assert (want > 0).any()
+    np.testing.assert_array_equal(got, want)
 
 
 def test_normalizer_matches_eq6():
