@@ -45,8 +45,7 @@ def main():
     ref = NomadProjection(cfg, strategy="local").fit(x, index=index)
     t_ref = time.time() - t0
 
-    # same estimator, different execution strategy — the whole migration
-    # from the old fit_distributed() free function is these two kwargs
+    # same estimator, different execution strategy: two kwargs
     if hier:
         mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         proj = NomadProjection(cfg, strategy="hierarchical", mesh=mesh, pod_axis="pod")

@@ -302,7 +302,6 @@ class NomadConfig:
     # loss (paper §3.3)
     n_noise: int = 64  # |M| noise samples per head
     n_exact_negatives: int = 16  # samples drawn from non-approximated cells
-    approximate_remote_only: bool = True  # R̃ = every cell except the head's own
     batch_size: int = 4_096  # heads sampled per step (E_{i~P_i} estimator)
 
     # schedule (paper §3.4): lr0 = n/10, linear anneal to 0, PCA init
@@ -314,9 +313,7 @@ class NomadConfig:
     seed: int = 0
 
     # distribution (paper Fig. 2 + our multi-pod extension)
-    mean_refresh_steps: int = 0  # 0 => once per epoch (paper); else every T steps
     hierarchical: bool = False  # pod-level super-means across the slow axis
-    n_cluster_groups: int = 0  # super-mean groups (0 => one per pod shard)
 
     # out-of-sample serving (repro.serve): place unseen points on a frozen
     # map. "auto" serves sharded exactly when >1 device is visible; queries

@@ -4,7 +4,7 @@ Clusters are sharded contiguously across devices: shard ``s`` of ``n``
 owns clusters ``[s·K/n, (s+1)·K/n)`` — each cluster is a component of the
 ANN graph (paper §3.2), so positive forces and exact in-cell negatives
 never leave the device. The only collective in the optimisation loop is
-the per-refresh all-gather of cluster means and (static) counts.
+the all-gather of cluster means at each dispatch's start.
 
 The epoch body is process-agnostic: built over a mesh that spans the
 **global** device pool (``jax.devices()``), its all-gathers/psums cross
@@ -22,13 +22,16 @@ Two exchange modes:
   Jensen+Taylor argument (paper §7) applied to the pod-level partition
   justifies the approximation; DCN bytes drop from K·d to pods·d.
 
-The SGD step body is ``repro.core.nomad.make_step_fn`` — identical math to
-the single-device reference, which is what the equivalence test checks.
+The SGD step and the epoch body are ``repro.core.nomad.make_step_fn`` and
+``scan_steps``, the functions the single-device fit runs: each shard draws
+its heads from its own cells, and the means are exchanged once, at the
+dispatch's start. On a one-device mesh the sharded fit is bit-equal to the
+local one (``tests/test_strategy.py``).
 
-Host-side orchestration lives in the unified estimator now
+Host-side orchestration lives in the unified estimator
 (:class:`repro.core.nomad.NomadProjection` + ``repro.core.strategy``); this
-module provides the ``shard_map`` epoch function those strategies wrap, and
-keeps :func:`fit_distributed` as a deprecation shim.
+module provides the ``shard_map`` epoch function those strategies wrap,
+and the placement of θ and the index on the mesh.
 """
 
 from __future__ import annotations
@@ -43,16 +46,13 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import NomadConfig
-from repro.core import losses
 from repro.core.nomad import (
-    SCOPE_GATHER,
     SCOPE_LOSS,
     SCOPE_MEANS,
     SCOPE_SAMPLE,
     local_means,
-    sample_in_cluster,
-    sample_points,
-    sgd_update,
+    make_step_fn,
+    scan_steps,
 )
 
 # ``jax.named_scope`` of the shards' collectives: the means' all-gather
@@ -80,39 +80,37 @@ def make_sharded_epoch_fn(
     steps_per_epoch: int,
     n_shards: int,
 ):
-    """Build ``epoch(theta, idx, lr0, lr1, key) -> (theta, mean_loss)``.
+    """Build ``epoch(theta, idx, counts_global, lr0, lr1, key) -> (theta,
+    mean_loss)``.
 
     ``theta``: (K·C, d) global view, rows sharded over ``shard_axes``
-    (+ ``pod_axis`` outermost if given). ``idx`` dict likewise row-sharded
-    except the replicated ``counts_global`` (:func:`place_index`); its
-    ``knn_idx`` holds global row ids, made shard-local per step.
+    (+ ``pod_axis`` outermost if given). ``idx`` dict likewise row-sharded;
+    ``counts_global`` is the replicated float32 counts (:func:`place_index`).
+    ``idx["knn_idx"]`` holds global row ids, made shard-local per step.
+    ``cfg.batch_size`` is per shard (the paper's is per GPU), so
+    ``ShardedStrategy`` gives each shard ``1/n_shards`` of the one-device
+    step count and an epoch still draws ~N heads.
     """
     C = cfg.cluster_capacity
     K = cfg.n_clusters
     Kl = K // n_shards
-    B, S, Mn = cfg.batch_size, cfg.n_exact_negatives, cfg.n_noise
-    # batch_size is PER SHARD (paper: per-GPU); one epoch still touches ~N
-    # heads because steps_per_epoch is divided by n_shards in fit_distributed.
-    B_local = B
-    refresh = cfg.mean_refresh_steps or steps_per_epoch
-    n_chunks = max(steps_per_epoch // refresh, 1)
     all_axes = ((pod_axis,) if pod_axis else ()) + tuple(shard_axes)
     hierarchical = cfg.hierarchical and pod_axis is not None
-    n_total = cfg.n_points
+    step_fn = make_step_fn(cfg)
 
     @jax.named_scope(SCOPE_MEANS)
     def gather_cells(theta_l, counts_l, counts_global, shard_off):
-        """Per-refresh exchange → (cell_means, cell_w, own-exclusion base).
+        """The exchange at a dispatch's start → ``(means, counts, cell_off)``.
 
-        Returns the means matrix the loss sees, its |M|·p weights, and the
-        global id offset of this shard's own cells within that matrix.
+        The means matrix the loss sees, the cell sizes that weight it, and
+        the offset of this shard's own cells within it (the step's
+        ``cells``).
         """
         means_l = local_means(theta_l, counts_l, C)  # (Kl, d)
         if not hierarchical:
             with jax.named_scope(SCOPE_EXCHANGE):
                 means_g = jax.lax.all_gather(means_l, all_axes, axis=0, tiled=True)
-            cell_w = float(Mn) * counts_global.astype(jnp.float32) / n_total
-            return means_g, cell_w, shard_off
+            return means_g, counts_global, shard_off
         # ---- hierarchical: full means intra-pod, super-means inter-pod ----
         with jax.named_scope(SCOPE_EXCHANGE):
             means_pod = jax.lax.all_gather(means_l, tuple(shard_axes), axis=0, tiled=True)
@@ -128,44 +126,11 @@ def make_sharded_epoch_fn(
             super_means = jax.lax.all_gather(super_mean[0], pod_axis, axis=0, tiled=False)
             super_counts = jax.lax.all_gather(jnp.sum(pod_counts), pod_axis, tiled=False)
         # own pod's super-mean is excluded (its cells are already exact/full)
-        own_pod = jax.lax.axis_index(pod_axis)
-        super_w = float(Mn) * super_counts / n_total
-        super_w = jnp.where(jnp.arange(n_pods) == own_pod, 0.0, super_w)
+        super_counts = jnp.where(jnp.arange(n_pods) == pod_idx, 0.0, super_counts)
         cell_means = jnp.concatenate([means_pod, super_means], axis=0)  # (Kp+P, d)
-        pod_cell_w = float(Mn) * pod_counts / n_total
-        cell_w = jnp.concatenate([pod_cell_w, super_w])
+        cell_counts = jnp.concatenate([pod_counts, super_counts])
         own_base = shard_off - pod_idx * Kp  # own cells indexed within the pod block
-        return cell_means, cell_w, own_base
-
-    def sgd_step(theta_l, idx_l, cell_means, cell_w, own_base, row_off, counts_l, lr, key):
-        with jax.named_scope(SCOPE_SAMPLE):
-            k_head, k_neg = jax.random.split(key)
-            rows, cl_local = sample_points(k_head, B_local, idx_l["cum_counts"], C)
-            pos_rows = idx_l["knn_idx"][rows] - row_off  # global → shard-local rows
-            pos_w = idx_l["knn_w"][rows]
-            neg_rows = sample_in_cluster(k_neg, cl_local, counts_l, C, S)
-            own_cell = cl_local + own_base
-            p_own = counts_l.astype(jnp.float32)[cl_local] / n_total
-            neg_w = jnp.broadcast_to((float(Mn) * p_own / S)[:, None], (B_local, S))
-        with jax.named_scope(SCOPE_GATHER):
-            th_i = theta_l[rows]
-            th_pos = theta_l[pos_rows]
-            th_neg = theta_l[neg_rows]
-        cell_means = jax.lax.stop_gradient(cell_means)
-
-        def loss_fn(ti, tp, tn):
-            # one fused registry kernel per step (jnp path ≡ the legacy
-            # mean-term + contrastive composition, bit-for-bit)
-            per_head = losses.nomad_step_term(
-                ti, tp, pos_w, tn, neg_w, cell_means, cell_w, own_cell,
-                cfg.resolved_kernel_impl(),
-            )
-            return jnp.mean(per_head)
-
-        return sgd_update(
-            theta_l, loss_fn, rows, pos_rows, neg_rows, th_i, th_pos, th_neg, lr,
-            cfg.resolved_kernel_impl(),
-        )
+        return cell_means, cell_counts, own_base
 
     row_spec = P((pod_axis,) + tuple(shard_axes) if pod_axis else shard_axes)
     specs_in = (
@@ -196,45 +161,12 @@ def make_sharded_epoch_fn(
             row_off = shard_off * C
             if n_shards > 1:  # decorrelate shards; 1 shard matches the local stream
                 key = jax.random.fold_in(key, shard_idx)
-        counts_l = idx_l["counts"]
-
-        def chunk_body(carry, c):
-            theta_l, t0 = carry
-            cell_means, cell_w, own_base = gather_cells(
-                theta_l, counts_l, counts_global, shard_off
-            )
-
-            def step_body(carry, t):
-                theta_l = carry
-                with jax.named_scope(SCOPE_SAMPLE):
-                    lr = lr0 + (lr1 - lr0) * (t / steps_per_epoch)
-                    step_key = jax.random.fold_in(key, t)
-                theta_l, loss = sgd_step(
-                    theta_l,
-                    idx_l,
-                    cell_means,
-                    cell_w,
-                    own_base,
-                    row_off,
-                    counts_l,
-                    lr,
-                    step_key,
-                )
-                return theta_l, loss
-
-            theta_l, losses_ = jax.lax.scan(
-                step_body, theta_l, t0 + jnp.arange(refresh)
-            )
-            with jax.named_scope(SCOPE_LOSS):
-                return (theta_l, t0 + refresh), jnp.mean(losses_)
-
-        (theta_l, _), chunk_losses = jax.lax.scan(
-            chunk_body, (theta_l, jnp.zeros((), jnp.int32)), jnp.arange(n_chunks)
+        cells = gather_cells(theta_l, idx_l["counts"], counts_global, shard_off)
+        theta_l, loss = scan_steps(
+            step_fn, steps_per_epoch, theta_l, idx_l, cells, lr0, lr1, key, row_off
         )
-        with jax.named_scope(SCOPE_LOSS):
-            loss = jnp.mean(chunk_losses)
-            with jax.named_scope(SCOPE_EXCHANGE):
-                loss = jax.lax.pmean(loss, all_axes)
+        with jax.named_scope(SCOPE_LOSS), jax.named_scope(SCOPE_EXCHANGE):
+            loss = jax.lax.pmean(loss, all_axes)
         return theta_l, loss
 
     return epoch
@@ -304,41 +236,3 @@ def place_index(index, mesh: Mesh, axes) -> tuple:
         lambda c: c.astype(jnp.float32), out_shardings=NamedSharding(mesh, P())
     )(counts)
     return idx, counts_global
-
-
-def fit_distributed(
-    cfg: NomadConfig,
-    x: np.ndarray,
-    mesh: Mesh,
-    *,
-    shard_axes=("data", "model"),
-    pod_axis: Optional[str] = None,
-    index=None,
-    theta0=None,
-    callback=None,
-):
-    """DEPRECATED shim — use the unified estimator instead:
-
-        NomadProjection(cfg, strategy="sharded", mesh=mesh).fit(x)
-
-    Delegates to :class:`repro.core.nomad.NomadProjection` and returns the
-    legacy ``(embedding, index, losses)`` tuple. Note the legacy ``callback``
-    now receives the *unpermuted* ``(N, out_dim)`` embedding, not the raw
-    sharded θ buffer.
-    """
-    import warnings
-
-    warnings.warn(
-        "fit_distributed is deprecated; use "
-        "NomadProjection(cfg, strategy='sharded'|'hierarchical', mesh=mesh).fit(x)",
-        DeprecationWarning,
-        stacklevel=2,
-    )
-    from repro.core.nomad import NomadProjection
-
-    strategy = "hierarchical" if (cfg.hierarchical and pod_axis) else "sharded"
-    est = NomadProjection(
-        cfg, strategy=strategy, mesh=mesh, shard_axes=shard_axes, pod_axis=pod_axis
-    )
-    res = est.fit(x, index=index, callback=callback, theta0=theta0)
-    return res.embedding, res.index, res.losses

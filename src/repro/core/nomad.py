@@ -1,10 +1,11 @@
 """NOMAD Projection driver (paper §3 end-to-end).
 
-``make_step_fn`` builds the jitted SGD step over a *local* cluster-major
-block of positions — the same function body serves the single-device
-reference (local = everything) and the ``shard_map`` distributed path
-(local = this shard's clusters, means/counts global). All index structures
-come from :mod:`repro.index.ann`.
+``make_step_fn`` builds the one SGD step over a cluster-major block of
+positions and ``scan_steps`` the one epoch body: the single-device fit
+(the block is everything), the partial refine (heads from a cell subset)
+and the ``shard_map`` path (the block is this shard's cells, the means
+exchanged) all run them. All index structures come from
+:mod:`repro.index.ann`.
 
 Method selection:
 * ``"nomad"``  — Eq. 3: remote cells via means (M̃), own cell sampled (M).
@@ -108,18 +109,42 @@ def sgd_update(theta, loss_fn, rows, pos_rows, neg_rows, th_i, th_pos, th_neg, l
     return theta, loss
 
 
+def affected_heads(cfg: NomadConfig):
+    """Head sampler of a partial refine: heads uniform over the valid rows
+    of the cells ``idx["aff_cells"]`` (cumulative counts
+    ``idx["aff_cum_counts"]``), as global rows and cell ids."""
+    B, C = cfg.batch_size, cfg.cluster_capacity
+
+    def heads(key, idx):
+        rows, a = sample_points(key, B, idx["aff_cum_counts"], C)
+        cell = idx["aff_cells"][a]
+        # the a-th affected cell's rows, moved to that cell's place in θ
+        return rows + (cell - a) * C, cell
+
+    return heads
+
+
 def make_step_fn(
     cfg: NomadConfig,
     *,
     method: str = "nomad",
-    cluster_offset: int = 0,
+    heads: Optional[Callable] = None,
     n_total: Optional[int] = None,
 ):
-    """Build ``step(theta, idx, state) -> (theta, loss)``.
+    """Build the fit step ``step(theta, idx, cells, lr, key, row_off=0) ->
+    (theta, loss)``, the one step of every strategy.
 
-    ``idx`` is a dict of local index arrays; ``state`` carries (means,
-    global_counts, lr, key). ``cluster_offset`` maps local cluster ids into
-    the global cell numbering (shard s owns cells [off, off + K_local)).
+    ``idx`` is a dict of index arrays over ``theta``'s rows: the whole
+    layout on one device, a shard's own cells on a mesh. ``heads(key, idx)
+    -> (rows, cell)`` draws the step's heads and their cells in ``idx``'s
+    numbering; the default draws them uniformly over every valid row
+    (``idx["cum_counts"]``), :func:`affected_heads` over a partial refine's
+    cells. ``cells`` is ``(means, counts, cell_off)``, held fixed over a
+    dispatch: the means the loss repels from, the cell sizes that weight
+    them, and the offset of ``idx``'s cell ids in that numbering (0 on one
+    device; see ``core/distributed.py:gather_cells`` on a mesh).
+    ``row_off`` is subtracted from the kNN ids, which ``place_index`` keeps
+    global on a mesh.
 
     The NOMAD branch runs the whole per-step loss through the fused
     ``"nomad_step"`` registry kernel (via :func:`losses.nomad_loss`):
@@ -131,91 +156,25 @@ def make_step_fn(
     n_total = n_total or cfg.n_points
     B, S, Mn = cfg.batch_size, cfg.n_exact_negatives, cfg.n_noise
     C = cfg.cluster_capacity
+    if heads is None:
 
-    def step(theta, idx, means, global_counts, lr, key):
+        def heads(key, idx):
+            return sample_points(key, B, idx["cum_counts"], C)
+
+    def step(theta, idx, cells, lr, key, row_off=0):
+        means, counts, cell_off = cells
         with jax.named_scope(SCOPE_SAMPLE):
             k_head, k_neg = jax.random.split(key)
-            rows, cl_local = sample_points(k_head, B, idx["cum_counts"], C)
-            pos_rows = idx["knn_idx"][rows]  # (B, k)
+            rows, cell = heads(k_head, idx)
+            pos_rows = idx["knn_idx"][rows] - row_off  # (B, k)
             pos_w = idx["knn_w"][rows]  # (B, k)
             if method == "infonc":
                 # Eq. 2 baseline: |M| noise tails uniform over the full support
                 neg_rows, _ = sample_points(k_neg, B * Mn, idx["cum_counts"], C)
                 neg_rows = neg_rows.reshape(B, Mn)
             else:
-                neg_rows = sample_in_cluster(k_neg, cl_local, idx["counts"], C, S)
-                cell_global = cl_local + cluster_offset
-        with jax.named_scope(SCOPE_GATHER):
-            th_i = theta[rows]
-            th_pos = theta[pos_rows]
-            th_neg = theta[neg_rows]
-
-        if method == "infonc":
-
-            def loss_fn(ti, tp, tn):
-                return losses.infonc_tsne_loss(ti, tp, pos_w, tn)
-
-        else:
-
-            def loss_fn(ti, tp, tn):
-                return losses.nomad_loss(
-                    ti,
-                    tp,
-                    pos_w,
-                    means,
-                    global_counts,
-                    cell_global,
-                    tn,
-                    n_noise=Mn,
-                    n_total=n_total,
-                    impl=cfg.resolved_kernel_impl(),
-                )
-
-        return sgd_update(
-            theta, loss_fn, rows, pos_rows, neg_rows, th_i, th_pos, th_neg, lr,
-            cfg.resolved_kernel_impl(),
-        )
-
-    return step
-
-
-def make_partial_step_fn(
-    cfg: NomadConfig,
-    *,
-    method: str = "nomad",
-    n_total: Optional[int] = None,
-):
-    """The :func:`make_step_fn` body with heads restricted to a cell subset.
-
-    ``idx`` additionally carries ``aff_cells`` (A,) global ids of the cells
-    a partial_fit touched and ``aff_cum_counts`` (A,) their cumulative real
-    counts: heads sample uniformly over the *affected* points only, mapped
-    to global rows through the affected→global cell indirection. Means,
-    global counts and the repulsive mass still span the full layout, so
-    the refined cells equilibrate against the whole map — but gradients
-    only ever land on rows of affected cells (positives are in-cluster,
-    negatives in-cell), leaving the rest of θ bit-identical.
-    """
-    n_total = n_total or cfg.n_points
-    B, S, Mn = cfg.batch_size, cfg.n_exact_negatives, cfg.n_noise
-    C = cfg.cluster_capacity
-
-    def step(theta, idx, means, global_counts, lr, key):
-        with jax.named_scope(SCOPE_SAMPLE):
-            k_head, k_neg = jax.random.split(key)
-            acum = idx["aff_cum_counts"]
-            u = jax.random.randint(k_head, (B,), 0, acum[-1])
-            a = jnp.searchsorted(acum, u, side="right").astype(jnp.int32)
-            start = jnp.where(a > 0, acum[a - 1], 0)
-            cell = idx["aff_cells"][a]  # global cell ids
-            rows = cell * C + (u - start)
-            pos_rows = idx["knn_idx"][rows]
-            pos_w = idx["knn_w"][rows]
-            if method == "infonc":
-                neg_rows, _ = sample_points(k_neg, B * Mn, idx["cum_counts"], C)
-                neg_rows = neg_rows.reshape(B, Mn)
-            else:
                 neg_rows = sample_in_cluster(k_neg, cell, idx["counts"], C, S)
+                own_cell = cell + cell_off
         with jax.named_scope(SCOPE_GATHER):
             th_i = theta[rows]
             th_pos = theta[pos_rows]
@@ -234,8 +193,8 @@ def make_partial_step_fn(
                     tp,
                     pos_w,
                     means,
-                    global_counts,
-                    cell,
+                    counts,
+                    own_cell,
                     tn,
                     n_noise=Mn,
                     n_total=n_total,
@@ -248,45 +207,39 @@ def make_partial_step_fn(
         )
 
     return step
+
+
+def scan_steps(step_fn, steps_per_epoch: int, theta, idx, cells, lr0, lr1, key, row_off=0):
+    """The epoch body of every strategy: ``steps_per_epoch`` steps in one
+    ``lax.scan``, against ``cells`` computed once at the dispatch's start
+    and held fixed (stop-gradient) over it, as in Fig. 2. Step ``t`` runs
+    at lr ``lr0 + (lr1 - lr0)·t/steps_per_epoch`` with key
+    ``fold_in(key, t)``. Returns (θ, the mean step loss)."""
+
+    def body(theta, t):
+        with jax.named_scope(SCOPE_SAMPLE):
+            lr = lr0 + (lr1 - lr0) * (t / steps_per_epoch)
+            step_key = jax.random.fold_in(key, t)
+        return step_fn(theta, idx, cells, lr, step_key, row_off)
+
+    theta, losses_ = jax.lax.scan(body, theta, jnp.arange(steps_per_epoch))
+    with jax.named_scope(SCOPE_LOSS):
+        return theta, jnp.mean(losses_)
 
 
 def make_epoch_fn(cfg: NomadConfig, step_fn, steps_per_epoch: int):
-    """jit-compiled epoch: refresh means once, then scan the SGD steps.
-
-    Mirrors Fig. 2: means are computed (and, in the distributed version,
-    all-gathered) once per epoch and held fixed (stop-gradient) within it.
-    ``mean_refresh_steps > 0`` refreshes more often (beyond-paper knob).
-    """
+    """jit-compiled one-device epoch: the means of every cell once, then
+    :func:`scan_steps`."""
     C = cfg.cluster_capacity
-    refresh = cfg.mean_refresh_steps or steps_per_epoch
 
     @jax.jit
     def epoch(theta, idx, lr0, lr1, epoch_key):
         with jax.named_scope(SCOPE_MEANS):
+            means = local_means(theta, idx["counts"], C)
             counts_f = idx["counts"].astype(jnp.float32)
-
-        def body(carry, t):
-            theta, means = carry
-            with jax.named_scope(SCOPE_MEANS):
-                means = jax.lax.cond(
-                    t % refresh == 0,
-                    lambda th: local_means(th, idx["counts"], C),
-                    lambda th: means,
-                    theta,
-                )
-            with jax.named_scope(SCOPE_SAMPLE):
-                lr = lr0 + (lr1 - lr0) * (t / steps_per_epoch)
-                key = jax.random.fold_in(epoch_key, t)
-            theta, loss = step_fn(theta, idx, means, counts_f, lr, key)
-            return (theta, means), loss
-
-        with jax.named_scope(SCOPE_MEANS):
-            means0 = local_means(theta, idx["counts"], C)
-        (theta, _), losses_ = jax.lax.scan(
-            body, (theta, means0), jnp.arange(steps_per_epoch)
+        return scan_steps(
+            step_fn, steps_per_epoch, theta, idx, (means, counts_f, 0), lr0, lr1, epoch_key
         )
-        with jax.named_scope(SCOPE_LOSS):
-            return theta, jnp.mean(losses_)
 
     return epoch
 
@@ -534,7 +487,6 @@ class NomadProjection:
         self,
         x: np.ndarray,
         index: "Optional[AnnIndex]" = None,
-        callback: Optional[Callable] = None,
         *,
         callbacks=None,
         resume: Optional[bool] = None,
@@ -552,9 +504,8 @@ class NomadProjection:
         ``cfg.chunk_rows`` set, fit(store) and fit(ndarray) of identical
         rows are bit-equal (chunking pins the f32 accumulation order).
 
-        ``callback`` is the deprecated bare ``fn(epoch, embedding, loss)``
-        form; prefer ``callbacks=`` with a
-        :class:`repro.core.strategy.FitCallbacks`.
+        ``callbacks`` is a :class:`repro.core.strategy.FitCallbacks` (or a
+        sequence of them) that receives the fit's events.
         """
         import os
         import warnings
@@ -579,7 +530,7 @@ class NomadProjection:
         cfg = self.cfg
         x = prepare_inputs(x, caller="fit", chunk_rows=cfg.chunk_rows)
         t0 = time.time()
-        events = as_callbacks(callbacks, callback)
+        events = as_callbacks(callbacks)
         resume = self._resume_default if resume is None else resume
         ckdir = cfg.checkpoint_dir
         if resume and not ckdir:
@@ -850,7 +801,7 @@ class NomadProjection:
             )
         cfg = self.cfg
         t0 = time.time()
-        events = as_callbacks(callbacks, None)
+        events = as_callbacks(callbacks)
         index, theta_rows, _base_dir = self._previous_state()
         if index.capacity != cfg.cluster_capacity:
             raise ValueError(

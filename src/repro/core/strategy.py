@@ -7,8 +7,8 @@ checkpointing; a strategy owns *where and how one epoch runs*:
   paper's single-GPU reference; the only strategy that supports the
   non-factorising ``"infonc"`` baseline).
 * :class:`ShardedStrategy`      — the paper's Fig. 2 multi-device mode:
-  cluster-sharded ``shard_map`` epochs with a flat per-refresh all-gather of
-  cell means (``core/distributed.py:make_sharded_epoch_fn``).
+  cluster-sharded ``shard_map`` epochs with a flat all-gather of the cell
+  means at each dispatch's start (``core/distributed.py:make_sharded_epoch_fn``).
 * :class:`HierarchicalStrategy` — the multi-pod extension: full means
   circulate intra-pod, remote pods are summarised by one super-mean each.
 
@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import warnings
-from typing import Callable, Optional, Sequence
+from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -128,44 +128,14 @@ class CallbackList(FitCallbacks):
             cb.on_checkpoint(event)
 
 
-class LegacyCallback(FitCallbacks):
-    """Adapter for the old bare ``callback(epoch, embedding, loss)``.
-
-    Unlike the pre-redesign behaviour (which leaked the raw cluster-major,
-    capacity-padded ``theta`` buffer), the adapter hands the *unpermuted*
-    ``(N, out_dim)`` embedding — the same array ``FitResult.embedding`` ends
-    up with.
-    """
-
-    def __init__(self, fn: Callable):
-        self.fn = fn
-
-    def on_epoch_end(self, event: EpochEndEvent) -> None:
-        self.fn(event.epoch, event.embedding, event.loss)
-
-
-def as_callbacks(
-    callbacks=None, legacy_callback: Optional[Callable] = None
-) -> Optional[FitCallbacks]:
-    """Normalise fit()'s callback arguments into one FitCallbacks (or None)."""
-    out = []
-    if callbacks is not None:
-        if isinstance(callbacks, FitCallbacks):
-            out.append(callbacks)
-        else:  # sequence of FitCallbacks
-            out.extend(callbacks)
-    if legacy_callback is not None:
-        warnings.warn(
-            "fit(callback=...) is deprecated; pass callbacks=FitCallbacks() "
-            "(see repro.core.strategy.FitCallbacks). The legacy callback now "
-            "receives the unpermuted (N, out_dim) embedding.",
-            DeprecationWarning,
-            stacklevel=3,
-        )
-        out.append(LegacyCallback(legacy_callback))
-    if not out:
+def as_callbacks(callbacks=None) -> Optional[FitCallbacks]:
+    """Normalise fit()'s ``callbacks`` argument into one FitCallbacks (or None)."""
+    if callbacks is None or isinstance(callbacks, FitCallbacks):
+        return callbacks
+    callbacks = list(callbacks)  # a sequence of FitCallbacks
+    if not callbacks:
         return None
-    return out[0] if len(out) == 1 else CallbackList(out)
+    return callbacks[0] if len(callbacks) == 1 else CallbackList(callbacks)
 
 
 # ---------------------------------------------------------------------------
@@ -245,9 +215,8 @@ class ExecutionStrategy:
     # -- introspection ---------------------------------------------------------
 
     def refreshes_per_epoch(self) -> int:
-        steps = self._steps
-        refresh = self._refresh
-        return max(1, -(-steps // refresh))
+        """The means are computed once at each dispatch's start."""
+        return 1
 
     def fetch(self, theta) -> np.ndarray:
         """θ → host array; gathers remote shards under multi-process jax."""
@@ -264,6 +233,16 @@ class ExecutionStrategy:
         }
 
 
+def device_index(index) -> dict:
+    """The index arrays the one-device fit step reads, on the device."""
+    return {
+        "knn_idx": jnp.asarray(index.knn_idx, jnp.int32),
+        "knn_w": jnp.asarray(index.knn_w, jnp.float32),
+        "counts": jnp.asarray(index.counts, jnp.int32),
+        "cum_counts": jnp.asarray(np.cumsum(index.counts), jnp.int32),
+    }
+
+
 class LocalStrategy(ExecutionStrategy):
     """Single-device reference loop (``core/nomad.py:make_epoch_fn``)."""
 
@@ -273,13 +252,7 @@ class LocalStrategy(ExecutionStrategy):
         from repro.core.nomad import make_epoch_fn, make_step_fn
 
         self._steps = cfg.resolved_steps_per_epoch()
-        self._refresh = cfg.mean_refresh_steps or self._steps
-        self._idx = {
-            "knn_idx": jnp.asarray(index.knn_idx, jnp.int32),
-            "knn_w": jnp.asarray(index.knn_w, jnp.float32),
-            "counts": jnp.asarray(index.counts, jnp.int32),
-            "cum_counts": jnp.asarray(np.cumsum(index.counts), jnp.int32),
-        }
+        self._idx = device_index(index)
         step_fn = make_step_fn(cfg, method=method)
         self._epoch_fn = make_epoch_fn(cfg, step_fn, self._steps)
         return jnp.asarray(theta0)
@@ -288,10 +261,10 @@ class LocalStrategy(ExecutionStrategy):
 class PartialRefineStrategy(ExecutionStrategy):
     """Refinement epochs restricted to the cells a ``partial_fit`` touched.
 
-    Same epoch contract as :class:`LocalStrategy` — means refreshed over
-    the **full** layout (repulsion still sees every cell), the usual
+    Same epoch as :class:`LocalStrategy` — means refreshed over the
+    **full** layout (repulsion still sees every cell), the same step and
     ``make_epoch_fn`` scan — but heads are sampled only from
-    ``affected_cells`` (:func:`repro.core.nomad.make_partial_step_fn`).
+    ``affected_cells`` (:func:`repro.core.nomad.affected_heads`).
     Positives come from the patched in-cluster kNN and negatives from the
     head's own cell, so gradients never reach a row outside the affected
     cells: everything the append didn't touch stays bit-identical, which
@@ -308,7 +281,7 @@ class PartialRefineStrategy(ExecutionStrategy):
         self.affected_cells = np.asarray(affected_cells, np.int32)
 
     def prepare(self, cfg, method, index, theta0):
-        from repro.core.nomad import make_epoch_fn, make_partial_step_fn
+        from repro.core.nomad import affected_heads, make_epoch_fn, make_step_fn
 
         if self.affected_cells.size == 0:
             raise ValueError("PartialRefineStrategy needs >=1 affected cell")
@@ -316,16 +289,14 @@ class PartialRefineStrategy(ExecutionStrategy):
         aff = self.affected_cells
         n_aff = int(counts[aff].sum())
         self._steps = max(1, -(-n_aff // cfg.batch_size))
-        self._refresh = cfg.mean_refresh_steps or self._steps
         self._idx = {
-            "knn_idx": jnp.asarray(index.knn_idx, jnp.int32),
-            "knn_w": jnp.asarray(index.knn_w, jnp.float32),
-            "counts": jnp.asarray(counts, jnp.int32),
-            "cum_counts": jnp.asarray(np.cumsum(counts), jnp.int32),
+            **device_index(index),
             "aff_cells": jnp.asarray(aff, jnp.int32),
             "aff_cum_counts": jnp.asarray(np.cumsum(counts[aff]), jnp.int32),
         }
-        step_fn = make_partial_step_fn(cfg, method=method, n_total=index.n_points)
+        step_fn = make_step_fn(
+            cfg, method=method, heads=affected_heads(cfg), n_total=index.n_points
+        )
         self._epoch_fn = make_epoch_fn(cfg, step_fn, self._steps)
         return jnp.asarray(theta0)
 
@@ -409,7 +380,6 @@ class ShardedStrategy(ExecutionStrategy):
         # shards work in parallel, so each runs 1/n_shards of the
         # single-device step count — per-epoch sample volume stays ≈ N.
         self._steps = max(1, -(-cfg.resolved_steps_per_epoch() // self.n_shards))
-        self._refresh = cfg.mean_refresh_steps or self._steps
 
         axes = ((self.pod_axis,) if self.pod_axis else ()) + self.shard_axes
         with jax.profiler.TraceAnnotation(SPAN_PLACE):

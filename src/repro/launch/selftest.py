@@ -69,20 +69,6 @@ def main() -> int:
     assert np.array_equal(emb, emb2), "distributed run is not deterministic"
     print("determinism: OK")
 
-    # --- 2b. the deprecation shim still serves the legacy tuple ----------------
-    import warnings
-
-    from repro.core.distributed import fit_distributed
-
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        emb_shim, _, _ = fit_distributed(
-            cfg.replace(n_epochs=2), x, mesh, index=index
-        )
-    assert any(issubclass(w.category, DeprecationWarning) for w in caught)
-    assert np.isfinite(emb_shim).all()
-    print("fit_distributed shim: OK (DeprecationWarning emitted)")
-
     # --- 3. hierarchical multi-pod ---------------------------------------------
     mesh3 = make_mesh((2, 2, 2), ("pod", "data", "model"))
     hier = NomadProjection(

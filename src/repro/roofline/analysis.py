@@ -181,7 +181,7 @@ def nomad_analytic_terms(cfg, n_chips: int, steps: int, hw: dict) -> dict:
     keeps them in VMEM. This computes what the TPU actually streams:
     per step, the gathered/scattered θ rows (heads + kNN tails + exact
     negatives, read+write) plus the kernel's true I/O (θ_i, μ, w in; s,
-    dθ out), plus one full pass over local θ per mean refresh.
+    dθ out), plus one full pass over local θ for the means of a dispatch.
     """
     d = cfg.out_dim
     B = cfg.batch_size  # per shard
@@ -194,10 +194,9 @@ def nomad_analytic_terms(cfg, n_chips: int, steps: int, hw: dict) -> dict:
         + 2 * B * 4  # s out / ḡ in
     )
     rows_local = (cfg.n_clusters // n_chips) * cfg.cluster_capacity
-    refreshes = max(steps // (cfg.mean_refresh_steps or steps), 1)
-    mem_bytes = per_step * steps + refreshes * rows_local * d * 4
+    mem_bytes = per_step * steps + rows_local * d * 4
     # the paper's point: the only wire traffic is the means exchange
-    coll_bytes = refreshes * cfg.n_clusters * (d + 1) * 4
+    coll_bytes = cfg.n_clusters * (d + 1) * 4
     flops = 3.0 * B * (cfg.n_neighbors + cfg.n_exact_negatives + cfg.n_clusters) * 8.0 * steps
     return {
         "compute_s": flops / hw["peak_flops"],

@@ -185,7 +185,8 @@ def _partial_build():
     idx = index_arrays(
         cfg, aff_cells=aff, aff_cum_counts=jnp.cumsum(jnp.full((2,), N_PER_CELL, jnp.int32))
     )
-    return compiled_text(lambda: local_epoch(cfg, nomad.make_partial_step_fn(cfg), idx))
+    step_fn = nomad.make_step_fn(cfg, heads=nomad.affected_heads(cfg))
+    return compiled_text(lambda: local_epoch(cfg, step_fn, idx))
 
 
 def test_partial_epoch_ops_carry_one_scope():
@@ -196,6 +197,22 @@ def test_partial_epoch_ops_carry_one_scope():
 
 def test_partial_epoch_scopes_are_metadata_only():
     assert lines(_partial_build()) == lines(unscoped(_partial_build))
+
+
+def test_local_dispatch_computes_the_means_once(monkeypatch):
+    """One dispatch computes the cell means once, at its start: tracing
+    the epoch calls ``local_means`` once."""
+    calls = []
+    real = nomad.local_means
+
+    def counting(*args):
+        calls.append(args)
+        return real(*args)
+
+    monkeypatch.setattr(nomad, "local_means", counting)
+    cfg = tiny_cfg()
+    local_epoch(cfg, nomad.make_step_fn(cfg), index_arrays(cfg))
+    assert len(calls) == 1
 
 
 def test_stage_scopes_are_single_path_components():

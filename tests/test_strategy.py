@@ -1,5 +1,5 @@
 """Unified NomadProjection front end: strategy selection, event callbacks,
-checkpoint/resume equivalence, and the fit_distributed deprecation shim.
+checkpoint/resume equivalence, and the strategies' shared fit step.
 
 Everything here runs on the single in-process CPU device — the sharded
 strategy is exercised on a 1-device mesh, where it must agree with the
@@ -21,6 +21,7 @@ from repro.core.strategy import (
     FitCallbacks,
     HierarchicalStrategy,
     LocalStrategy,
+    PartialRefineStrategy,
     ShardedStrategy,
     resolve_strategy,
 )
@@ -115,6 +116,29 @@ def test_local_and_sharded_agree_on_one_device(data, one_device_mesh):
     np.testing.assert_allclose(loc.losses, sh.losses, rtol=1e-6)
 
 
+@pytest.mark.parametrize("method", ["nomad", "infonc"])
+def test_partial_refine_over_every_cell_agrees_with_local(data, method):
+    """With every cell affected, the partial refine draws the local fit's
+    heads: one dispatch from the same θ and key gives the same θ and
+    loss, bit for bit."""
+    from repro.index.build import IndexBuilder
+
+    x, _ = data
+    index = IndexBuilder(CFG).build(x)
+    rows = index.n_clusters * index.capacity
+    theta0 = jax.random.normal(jax.random.key(0), (rows, CFG.out_dim)) * 1e-2
+    local, partial = LocalStrategy(), PartialRefineStrategy(np.arange(index.n_clusters))
+    th_l = local.prepare(CFG, method, index, theta0)
+    th_p = partial.prepare(CFG, method, index, theta0)
+    assert partial._steps == local._steps
+    lr0 = CFG.resolved_lr0()
+    th_l, loss_l = local.run_epoch(th_l, 0, lr0, lr0 / 2, jax.random.key(7))
+    th_p, loss_p = partial.run_epoch(th_p, 0, lr0, lr0 / 2, jax.random.key(7))
+    assert not np.array_equal(np.asarray(th_l), np.asarray(theta0))
+    np.testing.assert_array_equal(np.asarray(th_l), np.asarray(th_p))
+    assert loss_l == loss_p
+
+
 # ---------------------------------------------------------------------------
 # Event API
 # ---------------------------------------------------------------------------
@@ -168,17 +192,6 @@ def test_wants_embedding_false_skips_materialisation(data):
     cb = Cheap()
     NomadProjection(CFG.replace(n_epochs=2)).fit(x, callbacks=cb)
     assert cb.embs == [None, None]
-
-
-def test_legacy_callback_deprecated_and_unpermuted(data):
-    x, _ = data
-    got = []
-    with pytest.warns(DeprecationWarning, match="callback"):
-        NomadProjection(CFG.replace(n_epochs=2)).fit(
-            x, callback=lambda e, emb, loss: got.append((e, emb.shape, loss))
-        )
-    assert [g[0] for g in got] == [0, 1]
-    assert all(g[1] == (N, CFG.out_dim) for g in got)
 
 
 # ---------------------------------------------------------------------------
@@ -397,15 +410,3 @@ def test_method_from_config(data):
     assert np.isfinite(res.embedding).all()
     with pytest.raises(ValueError, match="unknown method"):
         NomadConfig(method="umap")
-
-
-def test_fit_distributed_shim_warns_and_matches(data, one_device_mesh):
-    x, _ = data
-    from repro.core.distributed import fit_distributed
-
-    ref = NomadProjection(CFG, strategy="sharded", mesh=one_device_mesh).fit(x)
-    with pytest.warns(DeprecationWarning, match="fit_distributed"):
-        emb, index, losses = fit_distributed(CFG, x, one_device_mesh,
-                                             shard_axes=("data",), index=ref.index)
-    np.testing.assert_array_equal(emb, ref.embedding)
-    assert losses == ref.losses

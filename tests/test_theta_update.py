@@ -194,7 +194,7 @@ def run_step(monkeypatch, make, cfg, theta, idx, means, update):
 
     monkeypatch.setattr(nomad, "sgd_update", recording)
     counts_f = idx["counts"].astype(jnp.float32)
-    theta, _ = make(cfg)(theta, idx, means, counts_f, 0.8, jax.random.key(3))
+    theta, _ = make(cfg)(theta, idx, (means, counts_f, 0), 0.8, jax.random.key(3))
     return theta, pairs[0]
 
 
@@ -225,7 +225,7 @@ def test_partial_step_leaves_unaffected_cells_bit_identical(monkeypatch, method,
     aff = np.array([1, 3], np.int32)
     counts = np.asarray(idx["counts"])
     idx = {**idx, "aff_cells": jnp.asarray(aff), "aff_cum_counts": jnp.asarray(np.cumsum(counts[aff]), jnp.int32)}
-    make = lambda c: nomad.make_partial_step_fn(c, method=method)  # noqa: E731
+    make = lambda c: nomad.make_step_fn(c, method=method, heads=nomad.affected_heads(c))  # noqa: E731
     new, (rows, updates) = run_step(monkeypatch, make, cfg, theta0, idx, means, nomad.sgd_update)
     ref, _ = run_step(monkeypatch, make, cfg, theta0, idx, means, three_scatters)
     assert_same_update(new, ref, theta0, rows, updates)
